@@ -1009,6 +1009,8 @@ def main(arch: str = "qwen3-8b", write_md: bool = True, **kw):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
     for name, us, derived in main():
         print(f"{name},{us:.3f},{derived}")
     print(f"wrote {MD_PATH}")

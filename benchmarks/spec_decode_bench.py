@@ -168,4 +168,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
     main()

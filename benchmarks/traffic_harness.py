@@ -259,4 +259,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
     sys.exit(0 if main() else 1)

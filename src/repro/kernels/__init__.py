@@ -6,11 +6,22 @@ Each kernel is a subpackage with the repo-standard triple:
   ops.py    — the jit'd public wrapper (shape plumbing, level knobs)
   ref.py    — the pure-jnp oracle the tests assert against
 
-The container is CPU-only: kernels target TPU (BlockSpec shapes chosen for
-VMEM/MXU) and are validated in ``interpret=True`` mode, which executes the
-kernel body on CPU.
+Kernels target the TPU (BlockSpec shapes chosen for VMEM/MXU).  Every
+``ops`` wrapper takes ``interpret=None`` and resolves it by backend
+(``backend.resolve_interpret``): compiled with Mosaic when JAX's default
+backend is a TPU, run by the Pallas interpreter on any other backend —
+which is how the CPU test suite executes the kernel bodies.
+``tests/test_chip_compile.py`` compiles the paged kernels for a
+described v5e chip at qwen3-8b widths, so a tiling the chip's compiler
+refuses fails the suite without a chip.
 
 Kernels:
+
+  paged_attention — block-table-aware decode / chunked-prefill attention
+                    straight off the paged KV pool (the served O6 step)
+
+The four below reproduce the paper's ladder on kernels; no model or
+serving path calls them, only their tests do:
 
   tiled_matmul    — the paper's Fig. 4 ladder transplanted to a TPU matmul:
                     block staging (O1), grid software pipelining (O2),

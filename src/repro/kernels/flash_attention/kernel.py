@@ -88,7 +88,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 )
 def flash_attention_pallas(q, k, v, *, causal: bool = True,
                            block_q: int = 128, block_k: int = 128,
-                           interpret: bool = True, n_heads: int = 0,
+                           interpret: bool, n_heads: int = 0,
                            n_kv_heads: int = 0):
     """q: (B*H, S, D) -> (B*H, S, D), same dtype as q.
 

@@ -2,11 +2,12 @@
 
 from __future__ import annotations
 
+from repro.kernels.backend import resolve_interpret
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
 
 
 def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True):
+                    block_k: int = 128, interpret=None):
     """q: (B, S, H, D); k, v: (B, S_kv, Hkv, D) with H % Hkv == 0 and
     S_kv >= S.
 
@@ -27,6 +28,7 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
 
     out = flash_attention_pallas(
         to_flat(q), to_flat(k), to_flat(v), causal=causal,
-        block_q=block_q, block_k=block_k, interpret=interpret,
+        block_q=block_q, block_k=block_k,
+        interpret=resolve_interpret(interpret),
         n_heads=H, n_kv_heads=Hkv)
     return out.reshape(B, H, S, D).transpose(0, 2, 1, 3)

@@ -64,7 +64,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, s0_ref,
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_pallas(x, dt, A, Bs, Cs, s0, *, chunk: int = 128,
-               interpret: bool = True):
+               interpret: bool):
     """x: (B, S, H, P); dt: (B, S, H); A: (H,); Bs, Cs: (B, S, N);
     s0: (B, H, P, N) f32.
 

@@ -1,4 +1,4 @@
-"""Block-table-aware paged decode attention — the gather-free O6 step.
+"""Block-table-aware paged attention — the gather-free O6 step.
 
 The paged serving rung's original step re-materializes a dense
 ``(B, max_seq, ...)`` view of every KV leaf from the block pool on every
@@ -12,41 +12,54 @@ actually references.
 Ladder mapping: streaming K/V one physical block at a time with
 VMEM-resident ``(m, l, acc)`` online-softmax state is the same blocked
 discipline as ``kernels/flash_attention`` (explicit caching +
-pipelining); the (batch, kv-head) grid dims are PE duplication.  GQA is
-handled by the grid, not by materializing repeated K/V: each kv-head
-program attends its ``G = H // KV`` query heads against one shared
-``(T, D)`` block slice.
+pipelining); the batch grid dim is PE duplication.  GQA is handled
+without materializing repeated K/V: each kv head's ``G = H // KV`` query
+heads attend one shared ``(T, D)`` slice of the streamed block.
 
-Grid: ``(B, KV, 2 * nb)`` with the block walk innermost (sequential).
-The walk is TWO passes over the slot's block list, phase = j // nb:
+One kernel serves every query length.  A slot carries ``Q >= 1`` query
+tokens (decode is ``Q == 1``; chunked prefill and speculative verify are
+``Q > 1``), laid out per kv head as ``G * Q`` g-major rows: row ``r`` is
+query position ``r % Q`` of query head ``h * G + r // Q``.
+
+Tiling.  The TPU block-shape rule wants the last two dims of every block
+divisible by (8, 128) or equal to the array's.  So every block spans the
+full trailing dims: the query block is a slot's whole ``(KV, G*Q, D)``
+tile, and the KV block is one whole ``(T, KV, D)`` pool row, so one DMA
+moves a physical block with every kv head in it.  The kv-head loop runs
+inside the kernel and reads head ``h`` as ``block[:, h, :]``.  (Viewing
+the pool as ``(R, T, KV*D)`` instead makes XLA copy the whole pool into
+that layout once the pool passes ~64 MB.)
+
+Grid: ``(B, 2 * nb)`` with the block walk innermost (sequential).  The
+walk is TWO passes over the slot's block list, phase = j // nb:
 
   phase 0 — online-softmax statistics: running row-max ``m`` (exact)
             and rescaled denominator ``l``;
   phase 1 — the weighted-value accumulation, with the probabilities
             rounded to the query dtype before the PV product.
 
-The two-pass structure is what makes the serving ladder's bit-identity
-contract *hold in practice*: the dense decode path computes bf16 scores
-(einsum output dtype), masks/softmaxes in f32, then rounds the
-probabilities back to bf16 before the PV einsum.  Phase 1 applies the
-same roundings in the same order (scores -> dt, probs -> dt, one final
-output round), so kernel-path logits track the gather-path logits to
-reduction-order noise (~1e-7) instead of bf16-rounding noise (~1e-2) —
-greedy argmax cannot realistically flip.  The extra K stream per tick is
-still O(blocks touched), nowhere near the gather step's dense copy.
+The two-pass structure keeps the kernel's rounding sites those of the
+dense decode path: bf16 scores (einsum output dtype), f32 mask and
+softmax, probabilities rounded back to bf16 before the PV product, one
+final output round.  Phase 1 applies the same roundings in the same
+order, so kernel-path logits track the gather-path logits to
+reduction-order noise instead of bf16-rounding noise.
 
-The block tables and lengths ride in as scalar-prefetch operands so the
-``BlockSpec`` index maps can turn a *logical* block index ``j % nb``
-into the *physical* pool row ``tables[b, j % nb]`` before the DMA is
-issued — the indirection happens in the index map, never as a gathered
-copy.
+The block tables and lengths ride in as scalar-prefetch operands (flat,
+so SMEM holds them unpadded) and the ``BlockSpec`` index maps turn a
+*logical* block index ``j % nb`` into the *physical* pool row
+``tables[b, j % nb]`` before the DMA is issued — the indirection happens
+in the index map, never as a gathered copy.  Narrow pools add their
+``(R, KV)`` f32 scales as two more flat scalar-prefetch operands.
 
-Masking uses -1e30 like the flash kernel: position ``idx = jj*T + t`` is
-valid iff ``idx < lengths[b]``.  Blocks entirely past ``lengths[b]`` are
-skipped (their table entries may be the NULL block; its DMA is cheap and
-its values are never read).  Callers guarantee ``lengths >= 1`` (the
-engine writes position ``p`` before attending, so the length is
-``p + 1``); the ``1e-30`` guard only protects the skipped-slot case.
+Masking uses -1e30 like the flash kernel.  Query position ``qi`` attends
+kv positions ``idx < length - (Q - 1 - qi)`` with ``length = start + Q``
+valid positions; for ``Q == 1`` that is ``idx < length``.  Blocks
+entirely past ``lengths[b]`` are skipped (their table entries may be the
+NULL block; its DMA is cheap and its values are never read).  Every
+row's limit is at least 1, so logical block 0 (walked first) always
+gives each row a valid score — ``m`` is real before any fully-masked
+block, whose ``exp(-1e30 - m)`` is then exactly 0.
 """
 
 from __future__ import annotations
@@ -69,30 +82,9 @@ def _dequant(raw, s, dt):
     return (raw.astype(jnp.float32) * s).astype(dt).astype(jnp.float32)
 
 
-def _scores(q_ref, k_ref, jj, length, *, scale, block_size, ks=None):
-    """Masked f32 scores for one (G, T) block, with the SAME rounding
-    discipline as the dense decode path: the qk product and the scale
-    multiply are rounded to the query dtype (the dense path's einsum
-    output dtype) before the f32 mask/softmax.  ``ks`` (narrow pools)
-    is this block's scalar K scale; the dequant rounds to the query
-    dtype first — the exact bits the gather path's dense view holds."""
-    dt = q_ref.dtype
-    q = q_ref[0].astype(jnp.float32)                # (G, D)
-    if ks is None:
-        k = k_ref[0, :, 0].astype(jnp.float32)      # (T, D)
-    else:
-        k = _dequant(k_ref[0, :, 0], ks, dt)        # (T, D)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)         # (G, T)
-    s = (s.astype(dt) * scale).astype(dt).astype(jnp.float32)
-    idx = jj * block_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    return jnp.where(idx < length, s, NEG_INF)
-
-
-def _paged_attn_kernel(tables_ref, lens_ref, *refs, scale: float,
-                       block_size: int, n_blocks: int,
-                       quantized: bool = False):
+def _paged_kernel(tables_ref, lens_ref, *refs, scale: float,
+                  block_size: int, n_blocks: int, n_kv: int, q_len: int,
+                  quantized: bool):
     if quantized:
         (kscale_ref, vscale_ref, q_ref, k_ref, v_ref, o_ref,
          m_ref, l_ref, acc_ref) = refs
@@ -100,10 +92,10 @@ def _paged_attn_kernel(tables_ref, lens_ref, *refs, scale: float,
         kscale_ref = vscale_ref = None
         q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
     b = pl.program_id(0)
-    h = pl.program_id(1)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
     jj = j % n_blocks                # logical block within the pass
     phase = j // n_blocks            # 0: (m, l) stats; 1: PV accumulate
+    dt = q_ref.dtype
 
     @pl.when(j == 0)
     def _init():
@@ -112,262 +104,131 @@ def _paged_attn_kernel(tables_ref, lens_ref, *refs, scale: float,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     length = lens_ref[b]
-    # Narrow pools: this block's scalar scales, read from the SMEM
-    # scalar-prefetch operands through the same table indirection the
-    # BlockSpec DMA uses.
-    row = tables_ref[b, jj]
-    ks = kscale_ref[row, h] if quantized else None
-    vs = vscale_ref[row, h] if quantized else None
-
+    row = tables_ref[b * n_blocks + jj]
     # Skip blocks entirely past this slot's valid prefix (no compute;
     # the NULL-block rows inactive table tails point at are never read).
     in_range = jj * block_size < length
 
-    @pl.when((phase == 0) & in_range)
-    def _stats():
-        s = _scores(q_ref, k_ref, jj, length, scale=scale,
-                    block_size=block_size, ks=ks)
-        m_prev = m_ref[...]                          # (G, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        m_ref[...] = m_new
+    def head_slice(ref, scale_ref, h):
+        """Kv head ``h``'s (T, D) slice of the streamed block, in f32;
+        narrow pools dequantize with this block's scalar scale, read
+        from SMEM through the same table indirection the DMA used."""
+        raw = ref[0, :, h, :]
+        if scale_ref is None:
+            return raw.astype(jnp.float32)
+        return _dequant(raw, scale_ref[row * n_kv + h], dt)
 
-    @pl.when((phase == 1) & in_range)
-    def _accumulate():
-        s = _scores(q_ref, k_ref, jj, length, scale=scale,
-                    block_size=block_size, ks=ks)
-        if quantized:
-            v = _dequant(v_ref[0, :, 0], vs, q_ref.dtype)   # (T, D)
-        else:
-            v = v_ref[0, :, 0].astype(jnp.float32)          # (T, D)
-        p = jnp.exp(s - m_ref[...]) / jnp.maximum(l_ref[...], 1e-30)
-        # Round the probabilities to the query dtype — the dense path's
-        # ``softmax(s).astype(dt)`` — so the PV product sees identical
-        # inputs to the gather step's einsum.
-        p = p.astype(q_ref.dtype).astype(jnp.float32)
-        acc_ref[...] += jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _done():
-        o_ref[0] = acc_ref[...].astype(o_ref.dtype)
-
-
-def _paged_prefill_kernel(tables_ref, lens_ref, *refs, scale: float,
-                          block_size: int, n_blocks: int, q_len: int,
-                          quantized: bool = False):
-    """Multi-query (qlen > 1) variant of ``_paged_attn_kernel``.
-
-    The q block carries ``G * Q`` rows (g-major: row r is query position
-    ``r % Q`` of query head ``r // Q``), and the causal mask is per ROW:
-    query position ``qi`` attends kv positions ``idx <= start + qi``,
-    i.e. ``idx < length - (Q - 1 - qi)`` with ``length = start + Q``.
-    With Q == 1 every expression degenerates to the decode kernel's —
-    same block layout, same mask, same rounding sites — so qlen==1 is
-    bit-identical to ``_paged_attn_kernel`` (locked by a kernel test).
-
-    Row safety: every row's limit is ``start + qi + 1 >= 1``, so logical
-    block 0 (walked first) always contributes at least one valid score
-    per row — ``m`` is real before any fully-masked block is seen, and a
-    fully-masked block then contributes ``exp(-1e30 - m) == 0``.
-    """
-    if quantized:
-        (kscale_ref, vscale_ref, q_ref, k_ref, v_ref, o_ref,
-         m_ref, l_ref, acc_ref) = refs
-    else:
-        kscale_ref = vscale_ref = None
-        q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
-    b = pl.program_id(0)
-    h = pl.program_id(1)
-    j = pl.program_id(2)
-    jj = j % n_blocks
-    phase = j // n_blocks
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    length = lens_ref[b]
-    in_range = jj * block_size < length
-    row = tables_ref[b, jj]
-    ks = kscale_ref[row, h] if quantized else None
-    vs = vscale_ref[row, h] if quantized else None
-
-    def scores():
-        s = _scores(q_ref, k_ref, jj, length, scale=scale,
-                    block_size=block_size, ks=ks)       # (G*Q, T)
-        qi = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) % q_len
+    def scores(h):
+        """Masked f32 scores (G*Q, T) of kv head ``h``, rounded like
+        the dense path: the qk product and the scale multiply round to
+        the query dtype before the f32 mask/softmax."""
+        q = q_ref[0, h].astype(jnp.float32)              # (G*Q, D)
+        s = jax.lax.dot_general(
+            q, head_slice(k_ref, kscale_ref, h), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)          # (G*Q, T)
+        s = (s.astype(dt) * scale).astype(dt).astype(jnp.float32)
         idx = jj * block_size + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1)
-        return jnp.where(idx < length - (q_len - 1 - qi), s, NEG_INF)
+        limit = length
+        if q_len > 1:
+            qi = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) % q_len
+            limit = length - (q_len - 1 - qi)
+        return jnp.where(idx < limit, s, NEG_INF)
 
     @pl.when((phase == 0) & in_range)
     def _stats():
-        s = scores()
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        m_ref[...] = m_new
+        for h in range(n_kv):
+            s = scores(h)
+            m_prev = m_ref[h]                            # (G*Q, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
+            m_ref[h] = m_new
 
     @pl.when((phase == 1) & in_range)
     def _accumulate():
-        s = scores()
-        if quantized:
-            v = _dequant(v_ref[0, :, 0], vs, q_ref.dtype)
-        else:
-            v = v_ref[0, :, 0].astype(jnp.float32)
-        p = jnp.exp(s - m_ref[...]) / jnp.maximum(l_ref[...], 1e-30)
-        p = p.astype(q_ref.dtype).astype(jnp.float32)
-        acc_ref[...] += jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        for h in range(n_kv):
+            s = scores(h)
+            v = head_slice(v_ref, vscale_ref, h)         # (T, D)
+            p = jnp.exp(s - m_ref[h]) / jnp.maximum(l_ref[h], 1e-30)
+            # Round the probabilities to the query dtype — the dense
+            # path's ``softmax(s).astype(dt)`` — so the PV product sees
+            # identical inputs to the gather step's einsum.
+            p = p.astype(dt).astype(jnp.float32)
+            acc_ref[h] += jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
-    @pl.when(j == pl.num_programs(2) - 1)
+    @pl.when(j == pl.num_programs(1) - 1)
     def _done():
         o_ref[0] = acc_ref[...].astype(o_ref.dtype)
-
-
-def _grid_args(quantized: bool, nb: int):
-    """(num_scalar_prefetch, q/kv/out index maps) for the two scalar
-    arities: unquantized kernels prefetch (tables, lengths); narrow
-    pools add the (R, KV) f32 K/V scale matrices, read in-kernel through
-    the same table indirection the BlockSpec DMA uses."""
-    if quantized:
-        q_map = lambda b, h, j, tbl, lens, ks, vs: (b, h, 0)   # noqa: E731
-        kv_map = lambda b, h, j, tbl, lens, ks, vs: (           # noqa: E731
-            tbl[b, j % nb], 0, h, 0)
-        return 4, q_map, kv_map
-    q_map = lambda b, h, j, tbl, lens: (b, h, 0)               # noqa: E731
-    kv_map = lambda b, h, j, tbl, lens: (                       # noqa: E731
-        tbl[b, j % nb], 0, h, 0)
-    return 2, q_map, kv_map
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_prefill_attention_pallas(q, k_pool, v_pool, tables, lengths,
                                    k_scale=None, v_scale=None, *,
-                                   interpret: bool = True):
+                                   interpret: bool):
     """q: (B, Q, H, D) — Q query tokens per slot, causally masked against
     a paged KV prefix whose last Q positions ARE those tokens;
-    k_pool/v_pool: (R, T, KV, D); tables: (B, nb); lengths: (B,) int32 =
-    start + Q valid positions per slot (the chunk's K/V already
+    k_pool/v_pool: (R, T, KV, D); tables: (B, nb) int32; lengths: (B,)
+    int32 = start + Q valid positions per slot (the chunk's K/V already
     appended); k_scale/v_scale: (R, KV) f32 per-block absmax scales when
     the pool is narrow.  Returns (B, Q, H, D) in q's dtype."""
     B, Q, H, D = q.shape
     R, T, KV, Dk = k_pool.shape
     assert Dk == D and v_pool.shape == k_pool.shape, (q.shape, k_pool.shape)
     assert H % KV == 0, (H, KV)
-    G = H // KV
+    GQ = H // KV * Q
     nb = tables.shape[1]
     assert tables.shape == (B, nb) and lengths.shape == (B,), (
         tables.shape, lengths.shape)
     quantized = k_scale is not None
+    operands = (tables.reshape(B * nb), lengths)
     if quantized:
         assert k_scale.shape == (R, KV) and v_scale.shape == (R, KV), (
             k_scale.shape, v_scale.shape)
-    scale = 1.0 / (D ** 0.5)
+        operands += (k_scale.reshape(R * KV), v_scale.reshape(R * KV))
 
-    # g-major row layout: (B, Q, H, D) -> (B, H*Q, D); kv-head h's block
-    # is rows [h*G*Q, (h+1)*G*Q) — row r is (head h*G + r//Q, query r%Q).
-    qr = q.transpose(0, 2, 1, 3).reshape(B, H * Q, D)
-
-    n_prefetch, q_map, kv_map = _grid_args(quantized, nb)
+    # g-major rows per kv head: (B, Q, H, D) -> (B, KV, G*Q, D).
+    qr = q.transpose(0, 2, 1, 3).reshape(B, KV, GQ, D)
+    q_map = lambda b, j, *_: (b, 0, 0, 0)                    # noqa: E731
+    # ONE physical pool block, all kv heads, selected through the table.
+    kv_map = lambda b, j, tbl, *_: (                             # noqa: E731
+        tbl[b * nb + j % nb], 0, 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=n_prefetch,
-        grid=(B, KV, 2 * nb),
+        num_scalar_prefetch=len(operands),
+        grid=(B, 2 * nb),
         in_specs=[
-            pl.BlockSpec((1, G * Q, D), q_map),
-            pl.BlockSpec((1, T, 1, D), kv_map),
-            pl.BlockSpec((1, T, 1, D), kv_map),
+            pl.BlockSpec((1, KV, GQ, D), q_map),
+            pl.BlockSpec((1, T, KV, D), kv_map),
+            pl.BlockSpec((1, T, KV, D), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, G * Q, D), q_map),
+        out_specs=pl.BlockSpec((1, KV, GQ, D), q_map),
         scratch_shapes=[
-            pltpu.VMEM((G * Q, 1), jnp.float32),
-            pltpu.VMEM((G * Q, 1), jnp.float32),
-            pltpu.VMEM((G * Q, D), jnp.float32),
+            pltpu.VMEM((KV, GQ, 1), jnp.float32),
+            pltpu.VMEM((KV, GQ, 1), jnp.float32),
+            pltpu.VMEM((KV, GQ, D), jnp.float32),
         ],
     )
-    kernel = functools.partial(_paged_prefill_kernel, scale=scale,
-                               block_size=T, n_blocks=nb, q_len=Q,
-                               quantized=quantized)
-    kw = {}
-    if not interpret:
-        kw["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-
-    operands = ((tables, lengths, k_scale, v_scale) if quantized
-                else (tables, lengths))
+    kernel = functools.partial(
+        _paged_kernel, scale=1.0 / (D ** 0.5), block_size=T, n_blocks=nb,
+        n_kv=KV, q_len=Q, quantized=quantized)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H * Q, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, KV, GQ, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-        **kw,
     )(*operands, qr, k_pool, v_pool)
     return out.reshape(B, H, Q, D).transpose(0, 2, 1, 3)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attention_pallas(q, k_pool, v_pool, tables, lengths,
-                           k_scale=None, v_scale=None, *,
-                           interpret: bool = True):
-    """q: (B, H, D); k_pool/v_pool: (R, T, KV, D); tables: (B, nb) int32
-    physical pool rows per logical block; lengths: (B,) int32 valid
-    positions per slot; k_scale/v_scale: (R, KV) f32 per-block absmax
-    scales when the pool is narrow.  Returns (B, H, D) in q's dtype."""
-    B, H, D = q.shape
-    R, T, KV, Dk = k_pool.shape
-    assert Dk == D and v_pool.shape == k_pool.shape, (q.shape, k_pool.shape)
-    assert H % KV == 0, (H, KV)
-    G = H // KV
-    nb = tables.shape[1]
-    assert tables.shape == (B, nb) and lengths.shape == (B,), (
-        tables.shape, lengths.shape)
-    quantized = k_scale is not None
-    if quantized:
-        assert k_scale.shape == (R, KV) and v_scale.shape == (R, KV), (
-            k_scale.shape, v_scale.shape)
-    scale = 1.0 / (D ** 0.5)
-
-    n_prefetch, q_map, kv_map = _grid_args(quantized, nb)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=n_prefetch,
-        grid=(B, KV, 2 * nb),
-        in_specs=[
-            # q heads for kv-head h: rows h*G .. h*G+G-1
-            pl.BlockSpec((1, G, D), q_map),
-            # ONE physical pool block, selected through the table
-            pl.BlockSpec((1, T, 1, D), kv_map),
-            pl.BlockSpec((1, T, 1, D), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, G, D), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, D), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(_paged_attn_kernel, scale=scale,
-                               block_size=T, n_blocks=nb,
-                               quantized=quantized)
-    kw = {}
-    if not interpret:
-        kw["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-
-    operands = ((tables, lengths, k_scale, v_scale) if quantized
-                else (tables, lengths))
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
-        interpret=interpret,
-        **kw,
-    )(*operands, q, k_pool, v_pool)
+                           k_scale=None, v_scale=None, *, interpret: bool):
+    """Decode: q (B, H, D), one query token per slot — the ``Q == 1``
+    case of :func:`paged_prefill_attention_pallas`.  Returns (B, H, D)."""
+    return paged_prefill_attention_pallas(
+        q[:, None], k_pool, v_pool, tables, lengths, k_scale, v_scale,
+        interpret=interpret)[:, 0]

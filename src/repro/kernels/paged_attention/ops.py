@@ -1,17 +1,35 @@
 """Public wrapper: block-table-aware paged decode attention.
 
 Unlike the flash wrapper there is no GQA repeat here at all: the kernel
-grid is (batch, kv-head, block), so each kv-head's ``G`` query heads
-share one streamed ``(T, D)`` block slice and the pool is never copied
-``H / Hkv`` times.
+streams one whole pool block per step and each kv head's ``G`` query
+heads share its ``(T, D)`` slice, so the pool is never copied
+``H / Hkv`` times.  ``interpret=None`` compiles the kernel on a TPU and
+interprets it elsewhere (``repro.kernels.backend``).
 """
 
 from __future__ import annotations
 
-import jax.numpy as jnp
+import functools
 
+import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from repro.kernels.backend import resolve_interpret
 from repro.kernels.paged_attention.kernel import (
     paged_attention_pallas, paged_prefill_attention_pallas)
+
+
+def _on_each_device(kernel, *args):
+    """Call ``kernel(*args)``.  A Mosaic kernel is a one-device program
+    that XLA cannot partition, so when the caller traces under a
+    multi-device mesh (``PlacementPlan.tracing``) it runs on every
+    device over replicated operands and returns a replicated result."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.empty and mesh.size > 1:
+        kernel = jax.shard_map(kernel, mesh=mesh, in_specs=P(),
+                               out_specs=P(), check_vma=False)
+    return kernel(*args)
 
 
 def _check_scales(k_pool, k_scale, v_scale):
@@ -26,7 +44,7 @@ def _check_scales(k_pool, k_scale, v_scale):
 
 
 def paged_attention(q, k_pool, v_pool, tables, lengths, *,
-                    k_scale=None, v_scale=None, interpret: bool = True):
+                    k_scale=None, v_scale=None, interpret=None):
     """Decode attention straight off a paged KV block pool.
 
     q: (B, H, D) — one query token per slot.
@@ -51,14 +69,15 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *,
         raise ValueError(f"pool/query shape mismatch: q {q.shape}, "
                          f"k {k_pool.shape}, v {v_pool.shape}")
     _check_scales(k_pool, k_scale, v_scale)
-    return paged_attention_pallas(
+    return _on_each_device(
+        functools.partial(paged_attention_pallas,
+                          interpret=resolve_interpret(interpret)),
         q, k_pool, v_pool, tables.astype(jnp.int32),
-        lengths.astype(jnp.int32), k_scale, v_scale, interpret=interpret)
+        lengths.astype(jnp.int32), k_scale, v_scale)
 
 
 def paged_prefill_attention(q, k_pool, v_pool, tables, lengths, *,
-                            k_scale=None, v_scale=None,
-                            interpret: bool = True):
+                            k_scale=None, v_scale=None, interpret=None):
     """Multi-token (qlen > 1) prefill attention off the paged pool — the
     chunked-prefill / speculative-decoding query mode.
 
@@ -81,6 +100,8 @@ def paged_prefill_attention(q, k_pool, v_pool, tables, lengths, *,
         raise ValueError(f"pool/query shape mismatch: q {q.shape}, "
                          f"k {k_pool.shape}, v {v_pool.shape}")
     _check_scales(k_pool, k_scale, v_scale)
-    return paged_prefill_attention_pallas(
+    return _on_each_device(
+        functools.partial(paged_prefill_attention_pallas,
+                          interpret=resolve_interpret(interpret)),
         q, k_pool, v_pool, tables.astype(jnp.int32),
-        lengths.astype(jnp.int32), k_scale, v_scale, interpret=interpret)
+        lengths.astype(jnp.int32), k_scale, v_scale)

@@ -71,7 +71,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref,
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def wkv_pallas(r, k, v, lw, u, s0, *, chunk: int = 128,
-               interpret: bool = True):
+               interpret: bool):
     """r,k,v,lw: (BH, S, N); u: (BH, N); s0: (BH, N, N) f32.
 
     Returns (y (BH, S, N) same dtype as r, final_state (BH, N, N) f32).

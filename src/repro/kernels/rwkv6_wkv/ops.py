@@ -4,11 +4,12 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro.kernels.backend import resolve_interpret
 from repro.kernels.rwkv6_wkv.kernel import wkv_pallas
 
 
 def wkv(r, k, v, lw, u, *, init_state=None, chunk: int = 128,
-        interpret: bool = True):
+        interpret=None):
     """Drop-in for ``models.rwkv6.wkv_chunked``.
 
     r,k,v,lw: (B, S, H, N); u: (H, N).
@@ -22,6 +23,6 @@ def wkv(r, k, v, lw, u, *, init_state=None, chunk: int = 128,
     s0_f = s0.reshape(B * H, N, N).astype(jnp.float32)
 
     y, sf = wkv_pallas(flat(r), flat(k), flat(v), flat(lw), u_f, s0_f,
-                       chunk=chunk, interpret=interpret)
+                       chunk=chunk, interpret=resolve_interpret(interpret))
     y = y.reshape(B, H, S, N).transpose(0, 2, 1, 3)
     return y, sf.reshape(B, H, N, N)

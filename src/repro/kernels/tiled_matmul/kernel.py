@@ -61,7 +61,7 @@ def _matmul_kernel_acc(a_ref, b_ref, o_ref, acc_ref):
                      "interpret"),
 )
 def matmul_pallas(a, b, *, bm: int, bn: int, bk: int, split_k: bool,
-                  parallel_mn: bool, interpret: bool = True):
+                  parallel_mn: bool, interpret: bool):
     """Blocked a @ b.  a: (M, K), b: (K, N) -> (M, N) float32.
 
     ``split_k=False`` -> O1 structure (K whole per tile);
@@ -118,7 +118,7 @@ def matmul_pallas(a, b, *, bm: int, bn: int, bk: int, split_k: bool,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def matmul_whole(a, b, *, interpret: bool = True):
+def matmul_whole(a, b, *, interpret: bool):
     """O0: one grid step, whole operands — no explicit caching."""
     M, K = a.shape
     _, N = b.shape

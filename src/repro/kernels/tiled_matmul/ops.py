@@ -13,6 +13,7 @@ import jax.numpy as jnp
 
 from repro.core.hw import TPU_V5E
 from repro.core.optlevel import OptLevel
+from repro.kernels.backend import resolve_interpret
 from repro.kernels.tiled_matmul.kernel import matmul_pallas, matmul_whole
 
 # VMEM working budget per core we allow kernels to claim (half of 128 MB,
@@ -50,10 +51,11 @@ def pick_blocks(M: int, N: int, K: int, *, level: OptLevel,
     return bm, bn, bk
 
 
-def matmul(a, b, level: OptLevel = OptLevel.O5, *, interpret: bool = True,
+def matmul(a, b, level: OptLevel = OptLevel.O5, *, interpret=None,
            blocks: tuple = None):
     """Best-effort blocked matmul.  Returns float32 (M, N)."""
     level = OptLevel(level)
+    interpret = resolve_interpret(interpret)
     M, K = a.shape
     _, N = b.shape
 

@@ -3,29 +3,21 @@ never touches jax device state)."""
 
 from __future__ import annotations
 
-import inspect
-
 import jax
+from jax.sharding import AxisType
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """Version-compat shim: ``jax.sharding.AxisType`` + the ``axis_types``
-    kwarg of ``jax.make_mesh`` only exist in newer JAX.  On older installs
-    (e.g. 0.4.x) every mesh axis is implicitly Auto, so omitting the kwarg
-    is semantically identical."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    if "axis_types" not in inspect.signature(jax.make_mesh).parameters:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+def _make_auto_mesh(shape, axes):
+    """A mesh whose axes are all ``Auto`` (the compiler propagates
+    shardings; ``jax.make_mesh`` defaults to ``Explicit``)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single pod (256 chips) or 2x16x16 (512 chips, 2 pods)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return _make_auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1, pod: int = 0):
@@ -34,7 +26,7 @@ def make_host_mesh(data: int = 1, model: int = 1, pod: int = 0):
         shape, axes = (pod, data, model), ("pod", "data", "model")
     else:
         shape, axes = (data, model), ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return _make_auto_mesh(shape, axes)
 
 
 def mesh_chips(mesh) -> int:

@@ -4,9 +4,12 @@ of the best-effort ladder.
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3-8b --smoke \
       --batch 4 --max-seq 64 --requests 8 --level 5 --policy spf
 
-On a real fleet the same driver builds the production mesh and the sharded
-``serve_step`` from ``launch/steps.py``; on this container it runs the
-reduced smoke config on the host device.  ``--level`` selects the
+This script runs one engine in one process on the devices JAX finds:
+``--smoke`` builds the reduced config, without it the published widths
+(qwen3-8b's need a bf16 ``param_dtype`` and a cut depth to fit one
+16 GB chip — see ``chip_smoke.py``).  It never builds a production
+mesh; multi-device placement is the PE-duplication mesh below.
+``--level`` selects the
 OptLevel the engine is built at (see ``repro.serving``; 6 = paged KV
 blocks, 7 = speculative decoding — pair it with ``--draft``); walk all
 eight with ``python -m repro.autotune --serve``.
@@ -196,4 +199,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
     main()

@@ -4,8 +4,12 @@ Memory discipline follows the paper's ladder: the *naive* (O0) formulation
 materializes the full (S, S) score tensor; the production path is the
 *chunked* formulation (O1 explicit caching + O2 pipelining via ``lax.scan``
 over query blocks) which keeps a (q_chunk, S) working set — the jnp analog
-of the Pallas flash kernel in ``repro/kernels/flash_attention.py`` (used on
-real TPU hardware; the scan form is what the dry-run lowers).
+of the Pallas flash kernel in ``repro/kernels/flash_attention``, which no
+model path calls.  Every path here is XLA except the paged serving hooks
+(:func:`paged_decode_attention`, :func:`paged_chunk_prefill_attention`),
+which call the block-table Pallas kernel in
+``repro/kernels/paged_attention`` — compiled on a TPU, interpreted on
+other backends.
 """
 
 from __future__ import annotations

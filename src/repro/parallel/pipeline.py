@@ -48,13 +48,9 @@ def pipeline_apply(stage_params, x_micro, *, stage_fn, mesh: Mesh,
         sidx = jax.lax.axis_index(axis)
         p = jax.tree.map(lambda t: t[0], params)
         # mark the carries as stage-varying (each stage holds different
-        # data); on older JAX (no jax.lax.pcast) shard_map values are
-        # unconditionally varying, so the cast is a no-op
-        pcast = getattr(jax.lax, "pcast", None)
-        var = ((lambda t: pcast(t, (axis,), to="varying")) if pcast
-               else (lambda t: t))
-        buf = var(jnp.zeros_like(xs[0]))               # resident activation
-        outs = var(jnp.zeros_like(xs))
+        # data)
+        buf = jax.lax.pcast(jnp.zeros_like(xs[0]), (axis,), to="varying")
+        outs = jax.lax.pcast(jnp.zeros_like(xs), (axis,), to="varying")
 
         def tick(t, carry):
             buf, outs = carry

@@ -23,6 +23,7 @@ degradations so none of them are silent.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 from typing import Optional
 
@@ -194,6 +195,15 @@ class PlacementPlan:
         if self.mesh is None:
             return leaf
         return jax.lax.with_sharding_constraint(leaf, self.axis(ax))
+
+    def tracing(self):
+        """Context to trace a step of this plan in: JAX's abstract mesh
+        is the plan's, so a Mosaic kernel inside the step runs on every
+        device over replicated operands (``kernels/paged_attention/ops``)
+        instead of failing to partition.  No-op when unsharded."""
+        if self.mesh is None:
+            return contextlib.nullcontext()
+        return jax.sharding.use_abstract_mesh(self.mesh.abstract_mesh)
 
     def constrain_replicated(self, leaf):
         """In-graph re-shard of ``leaf`` to fully replicated (identity

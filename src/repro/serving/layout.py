@@ -156,7 +156,7 @@ def make_paged_fused(model, sample, manager, constrain=None):
     return _fused
 
 
-def make_paged_kernel_fused(model, sample, manager, replicate=None):
+def make_paged_kernel_fused(model, sample, manager, placement):
     """The paged KERNEL step (``paged_attn="kernel"``): the model's
     ``paged_decode_step`` consumes the block pool + tables (+ state
     rows) + positions DIRECTLY — the per-tick O(B * max_seq) dense
@@ -168,23 +168,27 @@ def make_paged_kernel_fused(model, sample, manager, replicate=None):
     per-block scale subtree alongside and the kernel dequantizes each
     streamed block in place.
 
-    ``replicate`` (from a sharded placement): the Pallas kernel is a
-    single-device program, so under a BLOCK-axis-sharded pool the step
-    re-constrains the pool leaves to replicated in-graph for the kernel
-    call and ``out_shardings`` re-shards the written pool back onto the
-    block axis.  Correct everywhere; whether it *wins* there is the
-    autotuner's call, like every best-effort rung.
+    Under a sharded ``placement`` the Pallas kernel is a single-device
+    program: the step re-constrains the BLOCK-axis-sharded pool leaves
+    to replicated in-graph, traces under the placement's mesh so each
+    kernel call runs on every device over replicated operands
+    (``PlacementPlan.tracing``), and ``out_shardings`` re-shards the
+    written pool back onto the block axis.  Correct everywhere; whether
+    it *wins* there is the autotuner's call, like every best-effort rung.
     """
     quantized = manager.plan.quantized
     kv_dtype = manager.plan.kv_dtype
 
     def _fused(params, cache, *rest):
+        with placement.tracing():
+            return _step(params, cache, *rest)
+
+    def _step(params, cache, *rest):
         extras, (tokens, positions, seeds) = rest[:-3], rest[-3:]
         pool, scales = _split_cache(cache, quantized)
-        if replicate is not None:
-            pool = jax.tree.map(replicate, pool)
-            if scales is not None:
-                scales = jax.tree.map(replicate, scales)
+        pool = jax.tree.map(placement.constrain_replicated, pool)
+        if scales is not None:
+            scales = jax.tree.map(placement.constrain_replicated, scales)
         if quantized:
             logits, new_pool, new_scales = model.paged_decode_step(
                 params, pool, *extras, tokens, positions,
@@ -546,10 +550,8 @@ class PagedLayout(KVLayout):
             log.warning("%s", self.degrade_reason)
         sample = make_sampler(sampler_cfg)
         if use_kernel:
-            fused = make_paged_kernel_fused(
-                model, sample, manager,
-                replicate=placement.constrain_replicated
-                if placement.sharded else None)
+            fused = make_paged_kernel_fused(model, sample, manager,
+                                            placement)
         else:
             fused = make_paged_fused(
                 model, sample, manager,
@@ -675,20 +677,20 @@ class PagedLayout(KVLayout):
                 extras, (tokens, start) = rest[:-2], rest[-2:]
                 tables, _rows = _split_extras(manager, extras)
                 pool, scales = _split_cache(cache, quantized)
-                if placement.sharded:
-                    pool = jax.tree.map(placement.constrain_replicated,
-                                        pool)
-                    if scales is not None:
-                        scales = jax.tree.map(
-                            placement.constrain_replicated, scales)
-                if quantized:
-                    logits, new_pool, new_scales = model.paged_verify_step(
-                        params, pool, tables, tokens, start,
-                        scales=scales, kv_dtype=kv_dtype)
-                else:
-                    logits, new_pool = model.paged_verify_step(
-                        params, pool, tables, tokens, start)
-                    new_scales = None
+                pool = jax.tree.map(placement.constrain_replicated, pool)
+                if scales is not None:
+                    scales = jax.tree.map(placement.constrain_replicated,
+                                          scales)
+                with placement.tracing():
+                    if quantized:
+                        logits, new_pool, new_scales = (
+                            model.paged_verify_step(
+                                params, pool, tables, tokens, start,
+                                scales=scales, kv_dtype=kv_dtype))
+                    else:
+                        logits, new_pool = model.paged_verify_step(
+                            params, pool, tables, tokens, start)
+                        new_scales = None
                 return (sample(logits, None),
                         _join_cache(new_pool, new_scales, quantized))
         else:

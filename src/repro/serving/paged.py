@@ -902,6 +902,10 @@ class PagedCacheManager(PagedAllocator):
         return {"pool": pool_sh, "scale": scale_sh}
 
     def _put_host(self, arr):
+        # Upload a snapshot: admission and retirement rewrite the host
+        # tables in place while a dispatched step may still be reading
+        # them, and on the CPU backend an upload can alias host memory.
+        arr = np.array(arr, copy=True)
         if self.placement is not None and self.placement.sharded:
             return jax.device_put(arr, self.placement.replicated)
         return jnp.asarray(arr)

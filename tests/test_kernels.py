@@ -1,5 +1,6 @@
 """Pallas kernel sweeps: shapes x dtypes vs pure-jnp oracles
-(interpret=True executes the kernel bodies on CPU)."""
+(on the CPU the wrappers pick interpret mode, which executes the kernel
+bodies there)."""
 
 import jax
 import jax.numpy as jnp
@@ -318,7 +319,7 @@ def test_paged_prefill_attention_vs_ref(dims):
                                rtol=2e-4, atol=2e-5)
 
 
-from tests._hypothesis_compat import given, settings, st  # noqa: E402
+from hypothesis import given, settings, strategies as st  # noqa: E402
 
 
 @settings(max_examples=10, deadline=None)

@@ -7,7 +7,7 @@ steps are performance transforms, not semantic ones)."""
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.machsuite import KERNELS, aes, bfs, gemm, kmp, nw, sort, spmv, viterbi
 from repro.core.optlevel import OptLevel

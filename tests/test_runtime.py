@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.runtime import (int8_compress, int8_decompress, DelayedGradSync,
                            FaultInjector, Heartbeat, ResilientRunner)

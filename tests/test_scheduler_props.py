@@ -10,15 +10,12 @@ bookkeeping invariants the serving engine's correctness rests on —
     total, no block held twice or both held and free, retired slots hold
     nothing — across BOTH tick protocols (serial ``advance`` and the
     overlapped ``tick_advance``/``finalize`` split).
-
-Runs through ``tests/_hypothesis_compat``: real hypothesis when the
-environment has it, the deterministic fixed-seed fallback otherwise.
 """
 
 import numpy as np
 import pytest
 
-from tests._hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.serving import PagedAllocator, Request, Scheduler
 from repro.serving.paged import BlockAllocator, blocks_for

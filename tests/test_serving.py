@@ -593,7 +593,7 @@ def test_paged_kernel_compact_mid_flight_preserves_tokens():
 # semantics the paged kernel is diffed against)
 # ---------------------------------------------------------------------------
 
-from tests._hypothesis_compat import given, settings, st  # noqa: E402
+from hypothesis import given, settings, strategies as st  # noqa: E402
 
 
 @settings(max_examples=10, deadline=None)
@@ -735,6 +735,28 @@ def test_paged_tables_device_cache_invalidated_on_lifecycle():
     dev3 = mgr2.step_extras()[0]
     assert dev3 is not dev2                               # invalidated
     np.testing.assert_array_equal(np.asarray(dev3), mgr2.tables)
+
+
+def test_paged_tables_upload_is_a_snapshot():
+    """The uploaded block tables must not change when the host tables
+    are rewritten in place: retirement rewrites them while a dispatched
+    step may still read the upload, and the CPU backend aliases
+    64-byte-aligned host arrays instead of copying them (that alias
+    gave requests a garbage last token under the async front end)."""
+    _, model, _ = _model()
+    from repro.serving import PagedCacheManager
+    mgr = PagedCacheManager(model, 2, 16, block_size=4)
+    buf = np.empty(mgr.tables.size + 16, np.int32)
+    off = (-buf.ctypes.data % 64) // 4
+    aligned = buf[off:off + mgr.tables.size].reshape(mgr.tables.shape)
+    aligned[:] = mgr.tables
+    mgr.tables = aligned
+    req = Request(prompt=[1, 2], max_new_tokens=2)
+    mgr.admit_slot(0, req)
+    dev = mgr.step_extras()[0]
+    before = np.asarray(dev).copy()
+    mgr.release_slot(0, req)                   # rewrites row 0 to NULL
+    np.testing.assert_array_equal(np.asarray(dev), before)
 
 
 def test_step_cache_does_not_pin_dead_models():
