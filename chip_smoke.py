@@ -66,22 +66,8 @@ MAX_NEW = (16, 49)        # [lo, hi) generated tokens per request
 # replayed logits must meet.
 BF16_RTOL, BF16_ATOL = 0.06, 0.03
 
-COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-
-
 def log(msg: str) -> None:
     print(f"[smoke] {msg}", flush=True)
-
-
-class CompileClock:
-    """Seconds XLA spent compiling, from JAX's monitoring events."""
-
-    def __init__(self):
-        self.seconds = 0.0
-
-    def __call__(self, event, duration, **_):
-        if event == COMPILE_EVENT:
-            self.seconds += duration
 
 
 def peak_bytes(device) -> int:
@@ -203,14 +189,15 @@ def build_engine(model, params, *, level: int, pe: int, paged_attn: str,
             prefill_chunk=prefill_chunk))
 
 
-def serve(name: str, engine, trace, clock: CompileClock) -> list:
+def serve(name: str, engine, trace) -> list:
     """Serve ``trace`` through the async front end; check every request
     finished with its full token count; return the token lists in
     submission order."""
     import jax
     from repro.launch.server import serve_trace
+    from repro.serving.tracing import compile_seconds
 
-    c0, t0 = clock.seconds, time.monotonic()
+    c0, t0 = compile_seconds(), time.monotonic()
     out = serve_trace(engine, trace, max_ticks=20_000)
     wall = time.monotonic() - t0
     finished = out["finished"]
@@ -229,7 +216,7 @@ def serve(name: str, engine, trace, clock: CompileClock) -> list:
         f"requests={len(finished)} "
         f"prompt_tokens={sum(len(i.prompt) for i in trace)} "
         f"generated_tokens={sum(map(len, tokens))} ticks={out['ticks']} "
-        f"compile_s={clock.seconds - c0:.1f} smoke_wall_s={wall:.1f} "
+        f"compile_s={compile_seconds() - c0:.1f} smoke_wall_s={wall:.1f} "
         f"peak_bytes_in_use={peak_bytes(jax.devices()[0])}")
     return tokens
 
@@ -315,18 +302,19 @@ def explain_divergence(model, params, trace, ref_tokens, got_tokens, *,
                 f"{tie:.6f}")
 
 
-def one_chip(seed: int, clock: CompileClock) -> None:
+def one_chip(seed: int) -> None:
     import jax
 
     from repro.models import get_model
     from repro.serving import kvquant
+    from repro.serving.tracing import compile_seconds
 
     cfg = smoke_config()
-    c0, t0 = clock.seconds, time.monotonic()
+    c0, t0 = compile_seconds(), time.monotonic()
     kernel_phase(cfg, seed, rows=POOL_BLOCKS + 1, nb=MAX_SEQ // KV_BLOCK,
                  block=KV_BLOCK, decode_batch=BATCH,
                  prefill_len=PREFILL_CHUNK)
-    log(f"kernel phase passed: compile_s={clock.seconds - c0:.1f} "
+    log(f"kernel phase passed: compile_s={compile_seconds() - c0:.1f} "
         f"smoke_wall_s={time.monotonic() - t0:.1f}")
 
     model = get_model(cfg)
@@ -344,7 +332,7 @@ def one_chip(seed: int, clock: CompileClock) -> None:
 
     engine = build_engine(model, params, level=6, pe=1, paged_attn="kernel",
                           **sizes)
-    got = serve("engine O6 kernel", engine, trace, clock)
+    got = serve("engine O6 kernel", engine, trace)
     if engine.layout.attn_impl != "kernel" or engine.degrade_reason:
         raise RuntimeError(f"O6 engine degraded: attn_impl="
                            f"{engine.layout.attn_impl} reason="
@@ -356,7 +344,7 @@ def one_chip(seed: int, clock: CompileClock) -> None:
 
     engine = build_engine(model, params, level=5, pe=1, paged_attn="gather",
                           **sizes)
-    ref = serve("reference O5 dense", engine, trace, clock)
+    ref = serve("reference O5 dense", engine, trace)
     del engine
     contract = kvquant.tolerance_contract("bf16")
     try:
@@ -374,7 +362,7 @@ def one_chip(seed: int, clock: CompileClock) -> None:
             "within the bf16 logit tolerance")
 
 
-def four_chips(seed: int, clock: CompileClock) -> None:
+def four_chips(seed: int) -> None:
     import jax
 
     from repro.models import get_model
@@ -395,14 +383,14 @@ def four_chips(seed: int, clock: CompileClock) -> None:
                  pool_blocks=POOL_BLOCKS, prefill_chunk=0)
     engine = build_engine(model, params, level=6, pe=1, paged_attn="kernel",
                           **sizes)
-    one = serve("O6 kernel pe=1", engine, trace, clock)
+    one = serve("O6 kernel pe=1", engine, trace)
     del engine
     engine = build_engine(model, params, level=6, pe=4, paged_attn="kernel",
                           **sizes)
     if engine.placement.n_devices != 4:
         raise RuntimeError(f"pe=4 engine placed on "
                            f"{engine.placement.n_devices} device(s)")
-    four = serve("O6 kernel pe=4", engine, trace, clock)
+    four = serve("O6 kernel pe=4", engine, trace)
     if engine.layout.attn_impl != "kernel" or engine.degrade_reason:
         raise RuntimeError(f"pe=4 engine degraded: "
                            f"{engine.degrade_reason}")
@@ -440,17 +428,16 @@ def main(argv=None) -> int:
                          f"{dev.platform!r}")
     sys.path.insert(0, str(REPO / "src"))
     from repro.launch.compile_cache import configure_compile_cache
+    from repro.serving.tracing import compile_seconds
 
     cache_dir = configure_compile_cache()
-    clock = CompileClock()
-    jax.monitoring.register_event_duration_secs_listener(clock)
     log(f"device_kind={dev.device_kind} device_count={len(jax.devices())} "
         f"jax={jax.__version__} compile_cache={cache_dir}")
     if args.chips == 4:
-        four_chips(args.seed, clock)
+        four_chips(args.seed)
     else:
-        one_chip(args.seed, clock)
-    log(f"total compile_s={clock.seconds:.1f} "
+        one_chip(args.seed)
+    log(f"total compile_s={compile_seconds():.1f} "
         f"peak_bytes_in_use={peak_bytes(dev)}")
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,

@@ -49,6 +49,7 @@ import numpy as np
 
 from repro.serving.engine import DecodeEngine
 from repro.serving.scheduler import Request
+from repro.serving.tracing import span
 
 _END = object()          # stream sentinel: request finished
 
@@ -158,7 +159,9 @@ class AsyncServer:
             raise RuntimeError("server is not running")
         req = Request(prompt=list(prompt), max_new_tokens=max_new_tokens,
                       eos_id=eos_id, deadline_s=deadline_s)
-        self.engine.submit(req)         # validates; stamps arrival_s
+        with span("server.submit") as sp:
+            self.engine.submit(req)     # validates; stamps arrival_s
+            sp.set_metadata(rid=req.rid)
         handle = RequestHandle(req, asyncio.get_running_loop(),
                                on_token=on_token)
         if req.done:
@@ -185,23 +188,24 @@ class AsyncServer:
         correct under the O4 overlapped engine, whose finalize trails
         the dispatch frontier — a token is published the tick its
         bookkeeping lands, bit-identical to the sync path."""
-        now = time.monotonic()
-        for rid in list(self._handles):
-            h = self._handles[rid]
-            r = h.request
-            gen = r.generated
-            while h._published < len(gen):
-                ev = TokenEvent(rid=rid, token=gen[h._published],
-                                index=h._published, t_s=now)
-                h._published += 1
-                h.stream.put_nowait(ev)
-                if h.on_token is not None:
-                    h.on_token(ev)
-            if r.done:
-                del self._handles[rid]
-                h.stream.put_nowait(_END)
-                if not h.done.done():
-                    h.done.set_result(r)
+        with span("server.publish"):
+            now = time.monotonic()
+            for rid in list(self._handles):
+                h = self._handles[rid]
+                r = h.request
+                gen = r.generated
+                while h._published < len(gen):
+                    ev = TokenEvent(rid=rid, token=gen[h._published],
+                                    index=h._published, t_s=now)
+                    h._published += 1
+                    h.stream.put_nowait(ev)
+                    if h.on_token is not None:
+                        h.on_token(ev)
+                if r.done:
+                    del self._handles[rid]
+                    h.stream.put_nowait(_END)
+                    if not h.done.done():
+                        h.done.set_result(r)
 
     async def _loop(self) -> None:
         engine = self.engine

@@ -88,12 +88,15 @@ the paged layout), and ``generate()`` drains the decode loop.
 
 Admission, slot bookkeeping and retirement live in ``scheduler``; the
 engine is only the tick loop that wires scheduler, cache manager, sampler
-and overlap together under one config.
+and overlap together under one config.  Every tick is a ``serving.tick``
+span with a child span per phase, and ``engine.counters`` counts what the
+ticks did (``serving.tracing``).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Optional
 
 import numpy as np
@@ -106,6 +109,7 @@ from repro.serving.layout import select_layout, shared_steps
 from repro.serving.overlap import HostOverlap
 from repro.serving.sampler import SamplerConfig
 from repro.serving.scheduler import Request, Scheduler
+from repro.serving.tracing import Counters, span
 
 
 class TickBudgetExceeded(RuntimeError):
@@ -147,7 +151,11 @@ class DecodeEngine:
         self.config = config or BestEffortConfig(level=OptLevel.O5)
         self.level = self.config.level
         self.sampler_cfg = sampler or SamplerConfig()
-        self.scheduler = Scheduler(batch_size, max_seq, policy=policy)
+        # One record of counts, shared by the scheduler and the cache
+        # manager, which increment it where the work happens.
+        self.counters = Counters()
+        self.scheduler = Scheduler(batch_size, max_seq, policy=policy,
+                                   counters=self.counters)
         self.n_steps = 0
 
         # The two orthogonal serving axes, as strategy objects: cache
@@ -165,7 +173,8 @@ class DecodeEngine:
         self.placement = plan_pe_placement(self.config, batch_size)
         self.params = self.placement.put_replicated(params)
         self.cache_mgr = self.layout.build_manager(
-            model, batch_size, max_seq, self.config, self.placement)
+            model, batch_size, max_seq, self.config, self.placement,
+            self.counters)
         self.layout.wire_scheduler(self.scheduler, self.cache_mgr)
 
         self._fused = self.level.has(Step.PIPELINING) or step_fn is not None
@@ -228,12 +237,10 @@ class DecodeEngine:
         self.spec_mode = "off"
         self._draft_k = max(int(self.config.draft_k), 0)
         self._verify_fn = None
-        self.spec_drafted = self.spec_accepted = 0
-        self.spec_emitted = self.spec_ticks = self.spec_windows = 0
-        # Window baseline for spec_stats_window: counter values at the
-        # last snapshot reset (long-running servers need per-interval
-        # acceptance, not lifetime averages that drift stale).
-        self._spec_window_base = (0, 0, 0, 0, 0)
+        # Start of spec_stats_window's interval (long-running servers
+        # need per-interval acceptance, not lifetime averages that drift
+        # stale).
+        self._spec_window = self.counters.snapshot()
         self._dstate = [(-1, 0)] * batch_size   # per-slot (rid, drafter pos)
         spec_wanted = (self.level.has(Step.SPECULATIVE)
                        and (draft_model is not None
@@ -277,6 +284,19 @@ class DecodeEngine:
     def cache(self):
         return self.cache_mgr.cache
 
+    def _spec_view(self, c: Counters) -> dict:
+        drafted, windows = c.spec_drafted, c.spec_windows
+        return {
+            "spec_mode": self.spec_mode,
+            "draft_k": self._draft_k if self._spec else 0,
+            "drafted": drafted,
+            "accepted": c.spec_accepted,
+            "accept_rate": (c.spec_accepted / drafted) if drafted else 0.0,
+            "emitted": c.spec_emitted,
+            "eff_tok_per_step": (c.spec_emitted / windows) if windows
+            else 0.0,
+        }
+
     @property
     def spec_stats(self) -> dict:
         """Speculation counters: drafts proposed/accepted over the
@@ -285,18 +305,7 @@ class DecodeEngine:
         and ``eff_tok_per_step`` (tokens emitted per slot per verify
         window, in [1, K+1] and equal to ``1 + accept_rate * draft_k``
         absent mid-window retirements)."""
-        drafted = self.spec_drafted
-        windows = self.spec_windows
-        return {
-            "spec_mode": self.spec_mode,
-            "draft_k": self._draft_k if self._spec else 0,
-            "drafted": drafted,
-            "accepted": self.spec_accepted,
-            "accept_rate": (self.spec_accepted / drafted) if drafted else 0.0,
-            "emitted": self.spec_emitted,
-            "eff_tok_per_step": (self.spec_emitted / windows) if windows
-            else 0.0,
-        }
+        return self._spec_view(self.counters)
 
     def spec_stats_window(self, *, reset: bool = True) -> dict:
         """Speculation counters over the window SINCE the last reset —
@@ -306,22 +315,10 @@ class DecodeEngine:
         default) starts the next window at the current counters, so
         back-to-back calls bracket disjoint intervals.  The lifetime
         counters themselves are never rewound."""
-        base = self._spec_window_base
-        cur = (self.spec_drafted, self.spec_accepted, self.spec_emitted,
-               self.spec_ticks, self.spec_windows)
-        drafted, accepted, emitted, _ticks, windows = (
-            c - b for c, b in zip(cur, base))
+        out = self._spec_view(self.counters.since(self._spec_window))
         if reset:
-            self._spec_window_base = cur
-        return {
-            "spec_mode": self.spec_mode,
-            "draft_k": self._draft_k if self._spec else 0,
-            "drafted": drafted,
-            "accepted": accepted,
-            "accept_rate": (accepted / drafted) if drafted else 0.0,
-            "emitted": emitted,
-            "eff_tok_per_step": (emitted / windows) if windows else 0.0,
-        }
+            self._spec_window = self.counters.snapshot()
+        return out
 
     @property
     def queue(self):
@@ -442,14 +439,44 @@ class DecodeEngine:
         return self.run(max_ticks=max_ticks)
 
     def step(self) -> bool:
-        """One engine tick: admit, run the batched decode step, retire."""
-        if self._spec:
-            return self._step_spec()
-        if self._overlap is not None:
-            return self._step_overlapped()
-        return self._step_serial()
+        """One engine tick: admit, run the batched decode step, retire.
 
-    def _dispatch(self, tokens_np, positions_np, seeds_np, parked=None):
+        The tick is one ``serving.tick`` span; its metadata counts the
+        slots holding a request (``n``), those its decode dispatch
+        advanced (``gen``) and those it parked mid-prefill
+        (``parked``)."""
+        with span("serving.tick") as tick:
+            if self._spec:
+                progressed = self._step_spec(tick)
+            elif self._overlap is not None:
+                progressed = self._step_overlapped(tick)
+            else:
+                progressed = self._step_serial(tick)
+        if progressed:
+            self.counters.ticks += 1
+        return progressed
+
+    def _admit(self) -> list:
+        """One admission wave and the admitted slots' cache reset (the
+        ``serving.admit`` span).  Without chunked prefill the admitting
+        tick starts the prompt, so it stamps ``prefill_start_s``."""
+        sched = self.scheduler
+        c = self.counters
+        with span("serving.admit") as sp:
+            gated = c.admit_gated_pool
+            admitted = sched.admit()
+            if admitted:
+                self.cache_mgr.reset_slots(admitted, sched.active_indices)
+                if self._spec or self._prefill_fn is None:
+                    for i in admitted:
+                        r = sched.slots[i].req
+                        r.prefill_start_s = r.admit_s
+            sp.set_metadata(admitted=len(admitted), queued=len(sched.queue),
+                            gated=c.admit_gated_pool - gated)
+        return admitted
+
+    def _dispatch(self, tokens_np, positions_np, seeds_np, n_gen: int,
+                  parked=None):
         """Run the batched fused device step; returns the (possibly still
         in-flight) sampled tokens and installs the new cache.  The
         manager's ``step_extras()`` supplies any layout-specific step
@@ -459,14 +486,18 @@ class DecodeEngine:
         layout-blind.  ``parked`` names slots mid-chunked-prefill this
         tick: managers with carried state alias them to the NULL state
         row so the batched pad-feed cannot advance their real state
-        (their prompt advances only through ``_prefill_tick``)."""
-        toks_dev, new_cache = self._step_fn(
-            self.params, self.cache_mgr.cache,
-            *self.cache_mgr.step_extras(parked=parked),
-            jnp.asarray(tokens_np), jnp.asarray(positions_np),
-            jnp.asarray(seeds_np))
+        (their prompt advances only through ``_prefill_tick``).
+        ``n_gen`` is the number of slots the step advances."""
+        with span("serving.decode"):
+            toks_dev, new_cache = self._step_fn(
+                self.params, self.cache_mgr.cache,
+                *self.cache_mgr.step_extras(parked=parked),
+                jnp.asarray(tokens_np), jnp.asarray(positions_np),
+                jnp.asarray(seeds_np))
         self.cache_mgr.cache = new_cache
         self.n_steps += 1
+        self.counters.decode_dispatches += 1
+        self.counters.decode_slots += n_gen
         return toks_dev
 
     def _prefill_tick(self, i: int):
@@ -477,31 +508,43 @@ class DecodeEngine:
         LAST prompt token also emits the request's first generated token
         — sampled in-graph from the chunk's closing logits and handed to
         ``advance`` so all retirement logic is reused; earlier chunks
-        only move the position (``advance_chunk``)."""
+        only move the position (``advance_chunk``).  The final chunk
+        waits for that token (``serving.first_token``)."""
         sched = self.scheduler
+        c = self.counters
         s = sched.slots[i]
         r = s.req
         C = self._prefill_chunk
         start = s.pos
         n = min(C, r.n_prompt - start)
-        toks = np.full((1, C), self.pad_id, np.int32)
-        toks[0, :n] = r.prompt[start:start + n]
         final = start + n == r.n_prompt
-        cfg = self.sampler_cfg
-        seed = cfg.request_seed(r.rid, 0) if cfg.stochastic and final else 0
-        tok_dev, new_cache = self._prefill_fn(
-            self.params, self.cache_mgr.cache,
-            *self.cache_mgr.step_extras(),
-            jnp.int32(i), jnp.asarray(toks),
-            jnp.asarray([start], jnp.int32),
-            jnp.asarray([n - 1], jnp.int32),
-            jnp.asarray([seed], jnp.int32))
-        self.cache_mgr.cache = new_cache
-        if final:
-            sched.advance_chunk(i, n - 1)
-            sched.advance(i, int(np.asarray(tok_dev)))
-        else:
-            sched.advance_chunk(i, n)
+        with span("serving.prefill", rid=r.rid, slot=i, start=start, n=n,
+                  final=int(final)):
+            if r.prefill_start_s is None:
+                r.prefill_start_s = time.monotonic()
+            toks = np.full((1, C), self.pad_id, np.int32)
+            toks[0, :n] = r.prompt[start:start + n]
+            cfg = self.sampler_cfg
+            seed = (cfg.request_seed(r.rid, 0) if cfg.stochastic and final
+                    else 0)
+            tok_dev, new_cache = self._prefill_fn(
+                self.params, self.cache_mgr.cache,
+                *self.cache_mgr.step_extras(),
+                jnp.int32(i), jnp.asarray(toks),
+                jnp.asarray([start], jnp.int32),
+                jnp.asarray([n - 1], jnp.int32),
+                jnp.asarray([seed], jnp.int32))
+            self.cache_mgr.cache = new_cache
+            c.prefill_chunks += 1
+            c.prefill_tokens += n
+            if final:
+                sched.advance_chunk(i, n - 1)
+                with span("serving.first_token", rid=r.rid):
+                    tok = int(np.asarray(tok_dev))
+                c.first_token_syncs += 1
+                sched.advance(i, tok)
+            else:
+                sched.advance_chunk(i, n)
 
     # -- speculative decoding (O7) -------------------------------------------
     def _token_at(self, i: int, q: int) -> int:
@@ -593,7 +636,7 @@ class DecodeEngine:
             self._dstate[i] = (self._dstate[i][0], slots[i].pos + K)
         return drafts
 
-    def _step_spec(self) -> bool:
+    def _step_spec(self, tick) -> bool:
         """One speculative tick: draft K per generating slot, verify the
         whole batch's windows in ONE multi-token target forward, accept
         each slot's longest draft==argmax prefix plus the target's
@@ -605,9 +648,9 @@ class DecodeEngine:
         ticks."""
         sched = self.scheduler
         slots = sched.slots
-        admitted = sched.admit()
+        c = self.counters
+        self._admit()
         active = sched.active_indices
-        self.cache_mgr.reset_slots(admitted, active)
         if not active:
             return False
         K = self._draft_k
@@ -622,7 +665,10 @@ class DecodeEngine:
             else:
                 boundary.append(i)
 
-        drafts = self._draft_tokens(emit) if emit else {}
+        drafts = {}
+        if emit:
+            with span("serving.draft", slots=len(emit)):
+                drafts = self._draft_tokens(emit)
 
         greedy = None
         if emit or prefill:
@@ -641,13 +687,17 @@ class DecodeEngine:
                 n = min(W, r.n_prompt - s.pos)
                 tokens[i, :n] = r.prompt[s.pos:s.pos + n]
                 pf_real[i] = n
-            toks_dev, new_cache = self._verify_fn(
-                self.params, self.cache_mgr.cache,
-                *self.cache_mgr.step_extras(),
-                jnp.asarray(tokens), jnp.asarray(start))
-            self.cache_mgr.cache = new_cache
+            with span("serving.verify", slots=len(emit),
+                      prefill=len(prefill)):
+                toks_dev, new_cache = self._verify_fn(
+                    self.params, self.cache_mgr.cache,
+                    *self.cache_mgr.step_extras(),
+                    jnp.asarray(tokens), jnp.asarray(start))
+                self.cache_mgr.cache = new_cache
+                greedy = np.asarray(toks_dev).reshape(self.B, W)
             self.n_steps += 1
-            greedy = np.asarray(toks_dev).reshape(self.B, W)
+            c.decode_dispatches += 1
+            c.decode_slots += len(emit)
 
         btoks = None
         if boundary:
@@ -658,12 +708,13 @@ class DecodeEngine:
                 tokens_np[i, 0] = s.next_token()
                 positions_np[i] = s.pos
             toks_b = self._dispatch(tokens_np, positions_np,
-                                    np.zeros((self.B,), np.int32))
+                                    np.zeros((self.B,), np.int32),
+                                    len(boundary))
             btoks = np.asarray(toks_b).reshape(self.B, -1)[:, -1]
+        tick.set_metadata(n=len(active), gen=len(emit) + len(boundary),
+                          parked=0)
 
         # -- bookkeeping (host) ----------------------------------------------
-        if emit:
-            self.spec_ticks += 1
         for i in emit:
             g = greedy[i]
             d = drafts[i]
@@ -674,27 +725,30 @@ class DecodeEngine:
             rid = slots[i].req.rid
             window = [int(x) for x in g[:a + 1]]
             n_rec, _ = sched.advance_multi(i, window)
-            self.spec_drafted += K
-            self.spec_accepted += a
-            self.spec_emitted += n_rec
-            self.spec_windows += 1
+            c.spec_drafted += K
+            c.spec_accepted += a
+            c.spec_emitted += n_rec
+            c.spec_windows += 1
             # Truncate the drafter to what actually survived: positions
             # beyond pos + n_rec hold rejected-draft K/V, replayed from
             # the accepted tokens before the next draft attends them.
             self._dstate[i] = (rid, min(p + K, p + n_rec))
-        for i in prefill:
-            s = slots[i]
-            n = pf_real[i]
-            if s.pos + n == s.req.n_prompt:     # window closes the prompt
-                sched.advance_chunk(i, n - 1)
-                sched.advance(i, int(greedy[i][n - 1]))
-            else:
-                sched.advance_chunk(i, n)
+        if prefill:
+            # the prompt windows rode the verify forward
+            with span("serving.prefill", slots=len(prefill)):
+                for i in prefill:
+                    s = slots[i]
+                    n = pf_real[i]
+                    if s.pos + n == s.req.n_prompt:  # window closes prompt
+                        sched.advance_chunk(i, n - 1)
+                        sched.advance(i, int(greedy[i][n - 1]))
+                    else:
+                        sched.advance_chunk(i, n)
         for i in boundary:
             sched.advance(i, int(btoks[i]))
         return True
 
-    def _step_serial(self) -> bool:
+    def _step_serial(self, tick) -> bool:
         """O0..O3: admit -> fill -> dispatch -> wait -> retire, in order.
 
         Below O2 (no pipelining) each active request additionally runs its
@@ -702,9 +756,8 @@ class DecodeEngine:
         loop a batched tick replaces.
         """
         sched = self.scheduler
-        admitted = sched.admit()
+        self._admit()
         active = sched.active_indices
-        self.cache_mgr.reset_slots(admitted, active)
         if not active:
             return False
 
@@ -713,22 +766,28 @@ class DecodeEngine:
         if not self._fused:
             # O0/O1: one model call per request, host-side sampling.
             toks = np.zeros((self.B,), np.int32)
-            for i in active:
-                s = slots[i]
-                logits, self.cache_mgr.cache = self._single_fn(
-                    self.params, self.cache_mgr.cache,
-                    jnp.int32(s.next_token()), jnp.int32(s.pos),
-                    jnp.int32(i))
-                if self._host_greedy:
-                    toks[i] = int(np.asarray(logits).argmax())
-                else:
-                    seed = cfg.request_seed(s.req.rid, len(s.req.generated))
-                    toks[i] = int(self._sample_fn(
-                        jnp.asarray(logits)[None],
-                        jnp.asarray([seed], jnp.int32))[0])
+            with span("serving.decode"):
+                for i in active:
+                    s = slots[i]
+                    logits, self.cache_mgr.cache = self._single_fn(
+                        self.params, self.cache_mgr.cache,
+                        jnp.int32(s.next_token()), jnp.int32(s.pos),
+                        jnp.int32(i))
+                    if self._host_greedy:
+                        toks[i] = int(np.asarray(logits).argmax())
+                    else:
+                        seed = cfg.request_seed(s.req.rid,
+                                                len(s.req.generated))
+                        toks[i] = int(self._sample_fn(
+                            jnp.asarray(logits)[None],
+                            jnp.asarray([seed], jnp.int32))[0])
             self.n_steps += 1
-            for i in active:
-                sched.advance(i, toks[i])
+            self.counters.decode_dispatches += len(active)
+            self.counters.decode_slots += len(active)
+            tick.set_metadata(n=len(active), gen=len(active), parked=0)
+            with span("serving.advance"):
+                for i in active:
+                    sched.advance(i, toks[i])
             return True
 
         # Chunked prefill: one prompt chunk (head of the prefill queue)
@@ -748,30 +807,36 @@ class DecodeEngine:
             gen = [i for i in active
                    if slots[i].pos >= slots[i].req.n_prompt]
             if not gen:
+                tick.set_metadata(n=len(active), gen=0, parked=len(active))
                 return True                     # prefill-only tick
             parked = [i for i in active if i not in set(gen)]
         else:
             gen = active
+        tick.set_metadata(n=len(active), gen=len(gen),
+                          parked=len(parked or ()))
 
         # O2/O3: one batched fused step for every active slot.
-        tokens_np = np.asarray(
-            [[s.next_token() if s.active else self.pad_id]
-             for s in slots], np.int32)
-        positions_np = np.asarray(
-            [s.pos if s.active else 0 for s in slots], np.int32)
-        seeds_np = (np.asarray(
-            [cfg.request_seed(s.req.rid, len(s.req.generated))
-             if s.active else 0 for s in slots], np.int32)
-            if cfg.stochastic else np.zeros((self.B,), np.int32))
+        with span("serving.fill"):
+            tokens_np = np.asarray(
+                [[s.next_token() if s.active else self.pad_id]
+                 for s in slots], np.int32)
+            positions_np = np.asarray(
+                [s.pos if s.active else 0 for s in slots], np.int32)
+            seeds_np = (np.asarray(
+                [cfg.request_seed(s.req.rid, len(s.req.generated))
+                 if s.active else 0 for s in slots], np.int32)
+                if cfg.stochastic else np.zeros((self.B,), np.int32))
 
         toks_dev = self._dispatch(tokens_np, positions_np, seeds_np,
-                                  parked=parked)
-        toks = np.asarray(toks_dev).reshape(self.B, -1)[:, -1]
-        for i in gen:
-            sched.advance(i, toks[i])
+                                  len(gen), parked=parked)
+        with span("serving.finalize"):
+            with span("serving.fetch"):
+                toks = np.asarray(toks_dev).reshape(self.B, -1)[:, -1]
+            for i in gen:
+                sched.advance(i, toks[i])
         return True
 
-    def _step_overlapped(self) -> bool:
+    def _step_overlapped(self, tick) -> bool:
         """O4+: double-buffered schedule.  Each call finalizes the
         previous tick (its tokens have been computing since last call),
         dispatches this tick from mostly-prestaged buffers, then does all
@@ -781,32 +846,33 @@ class DecodeEngine:
         sched = self.scheduler
         cfg = self.sampler_cfg
         if self._pending is not None:
-            toks_dev, emissions = self._pending
-            self._pending = None
-            toks = np.asarray(toks_dev).reshape(self.B, -1)[:, -1]
-            sched.finalize(emissions, toks)
+            with span("serving.finalize"):
+                toks_dev, emissions = self._pending
+                self._pending = None
+                with span("serving.fetch"):
+                    toks = np.asarray(toks_dev).reshape(self.B, -1)[:, -1]
+                sched.finalize(emissions, toks)
         active = sched.active_indices
         if not active:
             # cold start / wake-up: nothing was admitted under a running
             # step, so admit + reset inline.
-            admitted = sched.admit()
-            if not admitted:
+            if not self._admit():
                 return False
             active = sched.active_indices
-            self.cache_mgr.reset_slots(admitted, active)
 
         # fill: only slots not prestaged during the previous tick
-        buf = self._overlap.rotate()
-        skip = self._overlap.prestaged
-        for i in active:
-            if i in skip:
-                continue
-            s = sched.slots[i]
-            buf.tokens[i, 0] = s.next_token()
-            buf.positions[i] = s.pos
-            if cfg.stochastic:
-                buf.seeds[i] = cfg.request_seed(
-                    s.req.rid, len(s.req.generated))
+        with span("serving.fill"):
+            buf = self._overlap.rotate()
+            skip = self._overlap.prestaged
+            for i in active:
+                if i in skip:
+                    continue
+                s = sched.slots[i]
+                buf.tokens[i, 0] = s.next_token()
+                buf.positions[i] = s.pos
+                if cfg.stochastic:
+                    buf.seeds[i] = cfg.request_seed(
+                        s.req.rid, len(s.req.generated))
 
         # Chunked prefill rides the overlap seam: prefilling slots are
         # parked — excluded from tick_advance (their positions move
@@ -820,9 +886,11 @@ class DecodeEngine:
         else:
             gen = active
             parked = None
+        tick.set_metadata(n=len(active), gen=len(gen),
+                          parked=len(parked or ()))
 
         toks_dev = self._dispatch(buf.tokens, buf.positions, buf.seeds,
-                                  parked=parked)
+                                  len(gen), parked=parked)
 
         # -- bookkeeping for the next tick, under the running step -----------
         # The chunk dispatch is queued behind the decode step (so the
@@ -831,12 +899,12 @@ class DecodeEngine:
             pf = sched.prefill_queue()
             if pf:
                 self._prefill_tick(pf[0])
-        emissions = sched.tick_advance(gen)
+        with span("serving.advance"):
+            emissions = sched.tick_advance(gen)
         self._pending = (toks_dev, emissions)
-        admitted = sched.admit()                 # refills planned-free slots
-        if admitted:
-            self.cache_mgr.reset_slots(admitted, sched.active_indices)
-        self._overlap.prestage(sched, cfg)
+        self._admit()                            # refills planned-free slots
+        with span("serving.prestage"):
+            self._overlap.prestage(sched, cfg)
         return True
 
     def run(self, *, max_ticks: int = 10_000) -> list:

@@ -333,7 +333,8 @@ class KVLayout:
                             drive this layout (the paged step needs the
                             block-table argument, so it cannot).
     ``build_manager``     — construct the cache manager, already placed
-                            per the :class:`PlacementPlan`.
+                            per the :class:`PlacementPlan`, counting
+                            into the engine's ``Counters``.
     ``wire_scheduler``    — attach admission gate / lifecycle hooks.
     ``make_step``         — the jitted fused decode+sample step for
                             (this layout) x (this placement).
@@ -368,7 +369,8 @@ class KVLayout:
     state_impl: str = "none"
     degrade_reason = None
 
-    def build_manager(self, model, batch_size, max_seq, config, placement):
+    def build_manager(self, model, batch_size, max_seq, config, placement,
+                      counters):
         raise NotImplementedError
 
     def wire_scheduler(self, scheduler, manager) -> None:
@@ -405,7 +407,7 @@ class ContiguousLayout(KVLayout):
     supports_step_fn = True
 
     def build_manager(self, model, batch_size, max_seq,
-                      config: BestEffortConfig, placement):
+                      config: BestEffortConfig, placement, counters):
         return CacheManager(
             model, batch_size, max_seq, config.level,
             shardings=placement.cache_shardings(model, batch_size, max_seq))
@@ -515,13 +517,13 @@ class PagedLayout(KVLayout):
         self.quantized = kvquant.is_quantized(kv_dtype)
 
     def build_manager(self, model, batch_size, max_seq,
-                      config: BestEffortConfig, placement):
+                      config: BestEffortConfig, placement, counters):
         return PagedCacheManager(
             model, batch_size, max_seq,
             block_size=config.kv_block_size,
             pool_blocks=config.kv_pool_blocks,
             placement=placement,
-            kv_dtype=self.kv_dtype)
+            kv_dtype=self.kv_dtype, counters=counters)
 
     def wire_scheduler(self, scheduler, manager) -> None:
         # The scheduler drives the block lifecycle: admission is gated

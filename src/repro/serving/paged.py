@@ -64,6 +64,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.serving import kvquant
+from repro.serving.tracing import Counters, span
 
 NULL_BLOCK = 0
 NULL_ROW = 0
@@ -253,6 +254,16 @@ class PagedAllocator:
     @property
     def capacity_tokens(self) -> int:
         return self.pool_blocks * self.block_size
+
+    def pool_usage(self, lengths) -> dict:
+        """Blocks reserved against blocks holding context, for slots
+        holding ``lengths`` tokens of context each (the scheduler's
+        per-slot positions): computed when asked, not every tick."""
+        return {"pool_blocks": self.pool_blocks,
+                "reserved_blocks": sum(self._held),
+                "context_blocks": sum(
+                    min(blocks_for(int(n), self.block_size), h)
+                    for n, h in zip(lengths, self._held))}
 
     def check_conservation(self) -> None:
         """allocated + free == total, and no block is in two places."""
@@ -812,7 +823,7 @@ class PagedCacheManager(PagedAllocator):
     def __init__(self, model, batch_size: int, max_seq: int, *,
                  block_size: int = 16, pool_blocks: int = 0,
                  defrag: bool = False, placement=None,
-                 kv_dtype: str = "bf16"):
+                 kv_dtype: str = "bf16", counters: Counters | None = None):
         super().__init__(batch_size, max_seq, block_size=block_size,
                          pool_blocks=pool_blocks, defrag=defrag)
         self.model = model
@@ -849,6 +860,7 @@ class PagedCacheManager(PagedAllocator):
             self.cache = jax.device_put(self.cache,
                                         self.pool_shardings(placement))
         self._state_zero = None
+        self.counters = counters if counters is not None else Counters()
         self._tables_dev = None     # cached device copy of the tables
         self._rows_dev = None       # cached device copy of the row map
 
@@ -930,7 +942,9 @@ class PagedCacheManager(PagedAllocator):
         out = []
         if self.has_blocks:
             if self._tables_dev is None:
-                self._tables_dev = self._put_host(self.tables)
+                with span("serving.tables"):
+                    self._tables_dev = self._put_host(self.tables)
+                self.counters.table_uploads += 1
             out.append(self._tables_dev)
         if self.state is not None:
             if parked:

@@ -45,6 +45,8 @@ import itertools
 import time
 from typing import Optional
 
+from repro.serving.tracing import Counters
+
 POLICIES = ("fcfs", "spf", "deadline")
 
 
@@ -67,6 +69,11 @@ class Request:
     # Lifecycle timestamps (``time.monotonic`` seconds), threaded through
     # for TTFT / per-token latency measurement under open-loop traffic:
     arrival_s: Optional[float] = None       # stamped at submit()/place()
+    admit_s: Optional[float] = None         # given a slot: admit()/place()
+    # the first tick that processes any of the prompt: its first chunk
+    # under chunked prefill, else the admitting tick (None for a prompt
+    # prefilled outside the engine and placed with place())
+    prefill_start_s: Optional[float] = None
     first_token_s: Optional[float] = None   # first generated token lands
     finish_s: Optional[float] = None        # retirement
     # Admission wave at which the request joined the queue — the aging
@@ -122,9 +129,11 @@ class Scheduler:
     """Queue + slot pool.  The engine asks it who to admit, feeds it the
     sampled token per slot per tick, and it decides retirement."""
 
-    def __init__(self, n_slots: int, max_seq: int, *, policy: str = "fcfs"):
+    def __init__(self, n_slots: int, max_seq: int, *, policy: str = "fcfs",
+                 counters: Optional[Counters] = None):
         if policy not in POLICIES:
             raise ValueError(f"unknown policy {policy!r}; choices: {POLICIES}")
+        self.counters = counters if counters is not None else Counters()
         self.n_slots = n_slots
         self.max_seq = max_seq
         self.policy = policy
@@ -221,18 +230,28 @@ class Scheduler:
         """
         self._wave += 1
         admitted = []
+        gated = False
+        now = time.monotonic()
         for i, slot in enumerate(self.slots):
             if slot.active or not self.queue:
                 continue
             at = self._next_index()
             if (self.admission_gate is not None
                     and not self.admission_gate(self.queue[at])):
+                gated = True
                 break
             req = self._pop(at)
+            req.admit_s = now
             self.slots[i] = Slot(req=req, pos=0)
             if self.on_admit is not None:
                 self.on_admit(i, req)
             admitted.append(i)
+        c = self.counters
+        c.admissions += len(admitted)
+        if gated:
+            c.admit_gated_pool += 1
+        elif self.queue:
+            c.admit_gated_slots += 1        # every slot taken
         return admitted
 
     @property
@@ -270,6 +289,8 @@ class Scheduler:
             req.rid = next(self._rid)
         if req.arrival_s is None:
             req.arrival_s = time.monotonic()
+        req.admit_s = time.monotonic()
+        self.counters.admissions += 1
         self.slots[i] = Slot(req=req, pos=req.n_prompt - 1)
         if self.on_admit is not None:
             self.on_admit(i, req)
