@@ -49,8 +49,17 @@ The block tables and lengths ride in as scalar-prefetch operands (flat,
 so SMEM holds them unpadded) and the ``BlockSpec`` index maps turn a
 *logical* block index ``j % nb`` into the *physical* pool row
 ``tables[b, j % nb]`` before the DMA is issued — the indirection happens
-in the index map, never as a gathered copy.  Narrow pools add their
-``(R, KV)`` f32 scales as two more flat scalar-prefetch operands.
+in the index map, never as a gathered copy.  The pool is the model's
+layer-stacked ``(L, R, T, KV, D)`` leaf, read through its free
+``(L * R, T, KV, D)`` view: layer ``l``'s rows start at ``l * R``, so
+the wrapper offsets the tables by that much and the kernel DMAs layer
+``l``'s blocks straight out of the stack.  A layer scan hands the kernel
+the whole stack it carries: no per-layer slice of the pool is built for
+the call, nor a restacked copy after it.  The index map stays a
+one-layer pool's: on a v5e at 32 decode slots, a 5-D block indexed by a
+prefetched layer cost 7% more kernel time, and adding a prefetched
+``l * R`` inside the map 3%.  Narrow pools add that layer's ``(R, KV)``
+f32 scales as two more flat scalar-prefetch operands.
 
 Masking uses -1e30 like the flash kernel.  Query position ``qi`` attends
 kv positions ``idx < length - (Q - 1 - qi)`` with ``length = start + Q``
@@ -84,7 +93,7 @@ def _dequant(raw, s, dt):
 
 def _paged_kernel(tables_ref, lens_ref, *refs, scale: float,
                   block_size: int, n_blocks: int, n_kv: int, q_len: int,
-                  quantized: bool):
+                  layer_rows: int, quantized: bool):
     if quantized:
         (kscale_ref, vscale_ref, q_ref, k_ref, v_ref, o_ref,
          m_ref, l_ref, acc_ref) = refs
@@ -104,7 +113,10 @@ def _paged_kernel(tables_ref, lens_ref, *refs, scale: float,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     length = lens_ref[b]
-    row = tables_ref[b * n_blocks + jj]
+    if quantized:
+        # The tables hold rows of the stacked pool's (L * R, T, KV, D)
+        # view; the scales are one layer's (R, KV): the row in the layer.
+        row = jax.lax.rem(tables_ref[b * n_blocks + jj], layer_rows)
     # Skip blocks entirely past this slot's valid prefix (no compute;
     # the NULL-block rows inactive table tails point at are never read).
     in_range = jj * block_size < length
@@ -167,16 +179,22 @@ def _paged_kernel(tables_ref, lens_ref, *refs, scale: float,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_prefill_attention_pallas(q, k_pool, v_pool, tables, lengths,
-                                   k_scale=None, v_scale=None, *,
-                                   interpret: bool):
+                                   k_scale=None, v_scale=None, layer=None,
+                                   *, interpret: bool):
     """q: (B, Q, H, D) — Q query tokens per slot, causally masked against
     a paged KV prefix whose last Q positions ARE those tokens;
-    k_pool/v_pool: (R, T, KV, D); tables: (B, nb) int32; lengths: (B,)
-    int32 = start + Q valid positions per slot (the chunk's K/V already
-    appended); k_scale/v_scale: (R, KV) f32 per-block absmax scales when
-    the pool is narrow.  Returns (B, Q, H, D) in q's dtype."""
+    k_pool/v_pool: the layer-stacked pool (L, R, T, KV, D) with ``layer``
+    an int32 scalar naming the layer to read, or one layer's pool
+    (R, T, KV, D) with ``layer`` None (the L == 1 stack); tables: (B, nb)
+    int32; lengths: (B,) int32 = start + Q valid positions per slot (the
+    chunk's K/V already appended); k_scale/v_scale: that layer's (R, KV)
+    f32 per-block absmax scales when the pool is narrow.  Returns
+    (B, Q, H, D) in q's dtype."""
+    if k_pool.ndim == 4:
+        assert layer is None, "a one-layer pool takes no layer index"
+        k_pool, v_pool, layer = k_pool[None], v_pool[None], 0
     B, Q, H, D = q.shape
-    R, T, KV, Dk = k_pool.shape
+    L, R, T, KV, Dk = k_pool.shape
     assert Dk == D and v_pool.shape == k_pool.shape, (q.shape, k_pool.shape)
     assert H % KV == 0, (H, KV)
     GQ = H // KV * Q
@@ -184,7 +202,10 @@ def paged_prefill_attention_pallas(q, k_pool, v_pool, tables, lengths,
     assert tables.shape == (B, nb) and lengths.shape == (B,), (
         tables.shape, lengths.shape)
     quantized = k_scale is not None
-    operands = (tables.reshape(B * nb), lengths)
+    # Layer ``layer``'s block r is row ``layer * R + r`` of the stack's
+    # (L * R, T, KV, D) view (a free reshape).
+    rows = tables + jnp.asarray(layer, jnp.int32) * R
+    operands = (rows.reshape(B * nb), lengths)
     if quantized:
         assert k_scale.shape == (R, KV) and v_scale.shape == (R, KV), (
             k_scale.shape, v_scale.shape)
@@ -213,7 +234,7 @@ def paged_prefill_attention_pallas(q, k_pool, v_pool, tables, lengths,
     )
     kernel = functools.partial(
         _paged_kernel, scale=1.0 / (D ** 0.5), block_size=T, n_blocks=nb,
-        n_kv=KV, q_len=Q, quantized=quantized)
+        n_kv=KV, q_len=Q, layer_rows=R, quantized=quantized)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -221,14 +242,16 @@ def paged_prefill_attention_pallas(q, k_pool, v_pool, tables, lengths,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(*operands, qr, k_pool, v_pool)
+    )(*operands, qr, k_pool.reshape(L * R, T, KV, D),
+      v_pool.reshape(L * R, T, KV, D))
     return out.reshape(B, H, Q, D).transpose(0, 2, 1, 3)
 
 
 def paged_attention_pallas(q, k_pool, v_pool, tables, lengths,
-                           k_scale=None, v_scale=None, *, interpret: bool):
+                           k_scale=None, v_scale=None, layer=None, *,
+                           interpret: bool):
     """Decode: q (B, H, D), one query token per slot — the ``Q == 1``
     case of :func:`paged_prefill_attention_pallas`.  Returns (B, H, D)."""
     return paged_prefill_attention_pallas(
         q[:, None], k_pool, v_pool, tables, lengths, k_scale, v_scale,
-        interpret=interpret)[:, 0]
+        layer, interpret=interpret)[:, 0]

@@ -339,29 +339,30 @@ def _quant_block_write(blk, sblk, write_fn, valid, kv_dtype, dt):
     return kvquant.quantize(wide, s, kv_dtype), s
 
 
-def paged_chunk_prefill_attention(params, x, kvs, tables, positions,
+def paged_chunk_prefill_attention(params, x, kvs, layer, tables, positions,
                                   lengths, *, n_heads, n_kv, head_dim,
                                   qk_norm=False, rope_theta=1e4,
                                   kv_dtype="bf16", start=None):
     """Prompt-chunk attention straight off the paged block pool — the
     qlen > 1 sibling of :func:`paged_decode_attention`.
 
-    x: (B, C, d); kvs: (k, v) pool leaves (R, T, KV, dh) — or
-    (k, v, k_scale, v_scale) with (R, 1, KV, 1) scales for narrow
-    pools; tables: (B, nb); positions: (B, C) absolute index per chunk
-    token (clipped for the padded tail — those writes go to
+    x: (B, C, d); kvs: the layer-stacked pool leaves (k, v), each
+    (L, R, T, KV, dh) — or (k, v, k_scale, v_scale) with (L, R, 1, KV, 1)
+    scales for narrow pools; ``layer``: int index of this layer, the only
+    one written and read; tables: (B, nb); positions: (B, C) absolute
+    index per chunk token (clipped for the padded tail — those writes go to
     in-reservation blocks or the NULL block, both write-garbage-safe);
     lengths: (B,) UNCLIPPED ``start + C`` so the kernel's per-row causal
     limits stay exact for the real rows even when the padded tail clips;
     ``start`` (B,) anchors the narrow pools' requant window.
-    Returns (out (B, C, d), new kv-leaf tuple).
+    Returns (out (B, C, d), the updated stacked kv-leaf tuple).
     """
     from repro.kernels.paged_attention.ops import paged_prefill_attention
 
     B, C, d = x.shape
     dt = x.dtype
     ck, cv, sk, sv = _unpack_paged(kvs)
-    T = ck.shape[1]
+    T = ck.shape[2]
     nb = tables.shape[1]
 
     q = jnp.einsum("btd,dhk->bthk", x, params["wq"].astype(dt))
@@ -376,9 +377,9 @@ def paged_chunk_prefill_attention(params, x, kvs, tables, positions,
     if sk is None:
         rows = jnp.take_along_axis(tables, positions // T, axis=1)  # (B, C)
         offs = positions % T
-        ck = ck.at[rows, offs].set(k.astype(ck.dtype))
-        cv = cv.at[rows, offs].set(v.astype(cv.dtype))
-        o = paged_prefill_attention(q, ck, cv, tables, lengths)
+        ck = ck.at[layer, rows, offs].set(k.astype(ck.dtype))
+        cv = cv.at[layer, rows, offs].set(v.astype(cv.dtype))
+        o = paged_prefill_attention(q, ck, cv, tables, lengths, layer=layer)
         out = jnp.einsum("bthk,hkd->btd", o.astype(dt),
                          params["wo"].astype(dt))
         return out, (ck, cv)
@@ -403,27 +404,27 @@ def paged_chunk_prefill_attention(params, x, kvs, tables, positions,
     abs_idx = jbs[:, :, None] * T + jnp.arange(T)[None, None]  # (B, nt, T)
     valid = (abs_idx < lengths[:, None, None])[..., None, None]
 
-    ck, nsk = _quant_block_write(
-        ck[rows], sk[rows],
+    nck, nsk = _quant_block_write(
+        ck[layer, rows], sk[layer, rows],
         lambda w: w.at[bi, wi, woff].set(k.astype(dt)), valid, kv_dtype, dt)
-    cv, nsv = _quant_block_write(
-        cv[rows], sv[rows],
+    ncv, nsv = _quant_block_write(
+        cv[layer, rows], sv[layer, rows],
         lambda w: w.at[bi, wi, woff].set(v.astype(dt)), valid, kv_dtype, dt)
-    ck = kvs[0].at[rows].set(ck)
-    cv = kvs[1].at[rows].set(cv)
-    sk = sk.at[rows].set(nsk)
-    sv = sv.at[rows].set(nsv)
+    ck = ck.at[layer, rows].set(nck)
+    cv = cv.at[layer, rows].set(ncv)
+    sk = sk.at[layer, rows].set(nsk)
+    sv = sv.at[layer, rows].set(nsv)
 
     o = paged_prefill_attention(q, ck, cv, tables, lengths,
-                                k_scale=sk[:, 0, :, 0],
-                                v_scale=sv[:, 0, :, 0])
+                                k_scale=sk[layer, :, 0, :, 0],
+                                v_scale=sv[layer, :, 0, :, 0], layer=layer)
     out = jnp.einsum("bthk,hkd->btd", o.astype(dt), params["wo"].astype(dt))
     return out, (ck, cv, sk, sv)
 
 
-def paged_decode_attention(params, x, kvs, tables, positions, *, n_heads,
-                           n_kv, head_dim, qk_norm=False, rope_theta=1e4,
-                           kv_dtype="bf16"):
+def paged_decode_attention(params, x, kvs, layer, tables, positions, *,
+                           n_heads, n_kv, head_dim, qk_norm=False,
+                           rope_theta=1e4, kv_dtype="bf16"):
     """Gather-free decode attention against a paged KV block pool.
 
     The paged-decode counterpart of :func:`decode_attention` (the other
@@ -434,21 +435,25 @@ def paged_decode_attention(params, x, kvs, tables, positions, *, n_heads,
     and runs the block-table-aware Pallas kernel, which walks the table
     and streams only the blocks each slot's table references.
 
-    x: (B, 1, d); kvs: (k, v) pool leaves (R, T, KV, dh), row 0 the
-    NULL block — or (k, v, k_scale, v_scale) with (R, 1, KV, 1) scales
-    for narrow pools, which re-quantize the slot's ACTIVE block around
-    the append (dequantize, write, mask the unwritten tail, rescale);
-    tables: (B, nb); positions: (B,) current index per slot.  Inactive
-    slots point every table entry at the NULL block, whose contents are
-    write-garbage by design — their outputs are discarded by the
-    engine.  Returns (out (B, 1, d), new kv-leaf tuple).
+    x: (B, 1, d); kvs: the layer-stacked pool leaves (k, v), each
+    (L, R, T, KV, dh), row 0 the NULL block — or (k, v, k_scale, v_scale)
+    with (L, R, 1, KV, 1) scales for narrow pools, which re-quantize the
+    slot's ACTIVE block around the append (dequantize, write, mask the
+    unwritten tail, rescale); ``layer``: int index of this layer — the
+    append writes ``[layer, row, off]`` and the kernel reads layer
+    ``layer`` in place, so a layer scan can carry the stacked pool
+    without slicing or restacking it; tables: (B, nb); positions: (B,)
+    current index per slot.  Inactive slots point every table entry at
+    the NULL block, whose contents are write-garbage by design — their
+    outputs are discarded by the engine.  Returns (out (B, 1, d), the
+    updated stacked kv-leaf tuple).
     """
     from repro.kernels.paged_attention.ops import paged_attention
 
     B, _, d = x.shape
     dt = x.dtype
     ck, cv, sk, sv = _unpack_paged(kvs)
-    T = ck.shape[1]
+    T = ck.shape[2]
 
     q = jnp.einsum("btd,dhk->bthk", x, params["wq"].astype(dt))
     k = jnp.einsum("btd,dhk->bthk", x, params["wk"].astype(dt))
@@ -466,9 +471,10 @@ def paged_decode_attention(params, x, kvs, tables, positions, *, n_heads,
     off = positions % T
 
     if sk is None:
-        ck = ck.at[row, off].set(k[:, 0].astype(ck.dtype))
-        cv = cv.at[row, off].set(v[:, 0].astype(cv.dtype))
-        o = paged_attention(q[:, 0], ck, cv, tables, positions + 1)
+        ck = ck.at[layer, row, off].set(k[:, 0].astype(ck.dtype))
+        cv = cv.at[layer, row, off].set(v[:, 0].astype(cv.dtype))
+        o = paged_attention(q[:, 0], ck, cv, tables, positions + 1,
+                            layer=layer)
         out = jnp.einsum("bhk,hkd->bd", o.astype(dt),
                          params["wo"].astype(dt))
         return out[:, None], (ck, cv)
@@ -476,17 +482,18 @@ def paged_decode_attention(params, x, kvs, tables, positions, *, n_heads,
     bi = jnp.arange(B)
     valid = (jnp.arange(T)[None, :] <= off[:, None])[..., None, None]
     nckb, nsk = _quant_block_write(
-        ck[row], sk[row],
+        ck[layer, row], sk[layer, row],
         lambda w: w.at[bi, off].set(k[:, 0].astype(dt)), valid, kv_dtype, dt)
     ncvb, nsv = _quant_block_write(
-        cv[row], sv[row],
+        cv[layer, row], sv[layer, row],
         lambda w: w.at[bi, off].set(v[:, 0].astype(dt)), valid, kv_dtype, dt)
-    ck = ck.at[row].set(nckb)
-    cv = cv.at[row].set(ncvb)
-    sk = sk.at[row].set(nsk)
-    sv = sv.at[row].set(nsv)
+    ck = ck.at[layer, row].set(nckb)
+    cv = cv.at[layer, row].set(ncvb)
+    sk = sk.at[layer, row].set(nsk)
+    sv = sv.at[layer, row].set(nsv)
 
     o = paged_attention(q[:, 0], ck, cv, tables, positions + 1,
-                        k_scale=sk[:, 0, :, 0], v_scale=sv[:, 0, :, 0])
+                        k_scale=sk[layer, :, 0, :, 0],
+                        v_scale=sv[layer, :, 0, :, 0], layer=layer)
     out = jnp.einsum("bhk,hkd->bd", o.astype(dt), params["wo"].astype(dt))
     return out[:, None], (ck, cv, sk, sv)

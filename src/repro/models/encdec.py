@@ -229,12 +229,12 @@ def paged_decode_step(cfg: ArchConfig, params, pool, tables, rows, tokens,
     if scales is not None:
         kv_leaves += (scales["self_kv"]["k"], scales["self_kv"]["v"])
 
-    def body(h, xs):
-        layer_params, ck, cv = xs[:3]
-        kvs = xs[3:]
+    def body(carry, xs):
+        h, kvs = carry
+        layer_params, ck, cv, layer = xs
         a, new_kvs = attn.paged_decode_attention(
             layer_params["attn"], rms_norm(h, layer_params["attn_norm"]),
-            kvs, tables, positions,
+            kvs, layer, tables, positions,
             n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads, head_dim=cfg.head_dim,
             rope_theta=cfg.rope_theta, kv_dtype=kv_dtype,
         )
@@ -248,11 +248,15 @@ def paged_decode_step(cfg: ArchConfig, params, pool, tables, rows, tokens,
         h = h + c
         m = mlp_apply(layer_params["mlp"],
                       rms_norm(h, layer_params["mlp_norm"]), cfg.mlp_kind)
-        return h + m, tuple(new_kvs)
+        return (h + m, tuple(new_kvs)), None
 
+    # The stacked self_kv leaves ride in the carry, written in place at
+    # each layer's index (see ``transformer._decode_layers``).
     from repro.models.loops import scan_or_unroll
-    h, new_kvs = scan_or_unroll(
-        body, h, (params["decoder"], cross["k"], cross["v"]) + kv_leaves,
+    (h, new_kvs), _ = scan_or_unroll(
+        body, (h, kv_leaves),
+        (params["decoder"], cross["k"], cross["v"],
+         jnp.arange(kv_leaves[0].shape[0], dtype=jnp.int32)),
         unroll=cfg.unroll_layers)
     h = rms_norm(h, params["final_norm"])
     logits = (h[:, 0] @ params["lm_head"].astype(dt)).astype(jnp.float32)

@@ -231,15 +231,14 @@ def paged_decode_step(cfg: ArchConfig, params, pool, tables, rows, tokens,
         return h + out, new_st
 
     def group(carry, xs):
-        h = carry
-        layer_group, st_group, proj = xs[:3]
-        kvs = xs[3:]
+        h, kvs = carry
+        layer_group, st_group, proj, app = xs
         h, new_states = scan_or_unroll(inner, h, (layer_group, st_group),
                                        unroll=cfg.unroll_layers)
         x = jnp.concatenate([h, emb0[:, None]], axis=-1) @ proj.astype(dt)
         a, new_kvs = attn.paged_decode_attention(
             params["shared"]["attn"],
-            rms_norm(x, params["shared"]["attn_norm"]), kvs, tables,
+            rms_norm(x, params["shared"]["attn_norm"]), kvs, app, tables,
             positions, n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
             head_dim=cfg.head_dim, rope_theta=cfg.rope_theta,
             kv_dtype=kv_dtype,
@@ -249,10 +248,14 @@ def paged_decode_step(cfg: ArchConfig, params, pool, tables, rows, tokens,
                       rms_norm(x, params["shared"]["mlp_norm"]),
                       cfg.mlp_kind)
         h = h + (x + m)
-        return h, (new_states, tuple(new_kvs))
+        return (h, tuple(new_kvs)), new_states
 
-    h, (new_m, new_kvs) = scan_or_unroll(
-        group, h, (grouped, mcache, params["app_proj"]) + kv_leaves,
+    # The stacked shared_kv leaves ride in the carry, written in place at
+    # each application's index (see ``transformer._decode_layers``).
+    (h, new_kvs), new_m = scan_or_unroll(
+        group, (h, kv_leaves),
+        (grouped, mcache, params["app_proj"],
+         jnp.arange(na, dtype=jnp.int32)),
         unroll=cfg.unroll_layers)
     h = rms_norm(h, params["final_norm"])
     logits = (h[:, 0] @ params["lm_head"].astype(dt)).astype(jnp.float32)

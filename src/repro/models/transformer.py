@@ -154,7 +154,7 @@ def init_cache(cfg: ArchConfig, batch: int, max_seq: int,
 
 
 def _decode_layers(cfg: ArchConfig, params, kv_leaves, tokens, attn_body,
-                   last=None, all_rows=False):
+                   last=None, all_rows=False, in_place=False):
     """Shared decode skeleton: embed -> scan layers -> final norm ->
     logits.  ``attn_body`` is the pluggable decode-attention hook applied
     per layer — dense attention on a per-slot cache view
@@ -163,6 +163,16 @@ def _decode_layers(cfg: ArchConfig, params, kv_leaves, tokens, attn_body,
     matching stacked-over-layers cache leaves it consumes and rewrites —
     (k, v) for bf16 pools, (k, v, k_scale, v_scale) for narrow pools —
     and the same-arity tuple of new leaves comes back out.
+
+    By default the leaves are scanned as ``xs``/``ys``: ``attn_body``
+    gets layer l's slice of each and returns its new slice.  The paged
+    steps pass ``in_place=True``: the stacked leaves ride in the scan's
+    CARRY and ``attn_body(layer_params, hn, l, *stacked)`` gets the layer
+    index, writes its tokens at ``[l, ...]`` and hands the kernel the
+    whole stack.  A Pallas kernel is a custom call: as ``xs``, each
+    layer's pool would be copied out as its operand, and the ``ys``
+    restacked into a fresh pool that the donated input cannot alias.
+    The carried pool is updated in place.
 
     ``tokens`` may carry C >= 1 positions per row (chunked prefill).
     ``last`` (B,) selects the logits row per slot — the chunk's final
@@ -175,11 +185,9 @@ def _decode_layers(cfg: ArchConfig, params, kv_leaves, tokens, attn_body,
     dt = jnp.dtype(cfg.compute_dtype)
     h = params["embedding"].astype(dt)[tokens]           # (B, C, d)
 
-    def body(h, xs):
-        layer_params = xs[0]
+    def layer(h, layer_params, *kvs):
         a, new_kvs = attn_body(layer_params,
-                               rms_norm(h, layer_params["attn_norm"]),
-                               *xs[1:])
+                               rms_norm(h, layer_params["attn_norm"]), *kvs)
         h = h + a
         hn = rms_norm(h, layer_params["mlp_norm"])
         if cfg.n_experts:
@@ -192,9 +200,17 @@ def _decode_layers(cfg: ArchConfig, params, kv_leaves, tokens, attn_body,
         return h + m, tuple(new_kvs)
 
     from repro.models.loops import scan_or_unroll
-    h, new_leaves = scan_or_unroll(body, h,
-                                   (params["layers"],) + tuple(kv_leaves),
-                                   unroll=cfg.unroll_layers)
+    if in_place:
+        layer_ids = jnp.arange(kv_leaves[0].shape[0], dtype=jnp.int32)
+        (h, new_leaves), _ = scan_or_unroll(
+            lambda carry, xs: (layer(carry[0], xs[0], xs[1], *carry[1]),
+                               None),
+            (h, tuple(kv_leaves)), (params["layers"], layer_ids),
+            unroll=cfg.unroll_layers)
+    else:
+        h, new_leaves = scan_or_unroll(
+            lambda h, xs: layer(h, *xs), h,
+            (params["layers"],) + tuple(kv_leaves), unroll=cfg.unroll_layers)
     h = rms_norm(h, params["final_norm"])
     if all_rows:
         w = params["lm_head"].astype(dt)
@@ -226,8 +242,8 @@ def decode_step(cfg: ArchConfig, params, cache, tokens, positions):
 
 
 def _paged_leaves(pool, scales):
-    """The kv-leaf tuple a paged step scans over: (k, v) plus, for
-    narrow pools, the per-layer (L, R, 1, KV, 1) scale leaves."""
+    """The kv-leaf tuple a paged step carries through its layer scan:
+    (k, v) plus, for narrow pools, the (L, R, 1, KV, 1) scale leaves."""
     if scales is None:
         return (pool["k"], pool["v"])
     return (pool["k"], pool["v"], scales["k"], scales["v"])
@@ -246,7 +262,9 @@ def paged_decode_step(cfg: ArchConfig, params, pool, tables, tokens,
     """Gather-free paged decode step (the serving O6 kernel path).
 
     Identical layer structure to :func:`decode_step`, but each layer's
-    attention consumes the raw block-pool leaves (R, T, KV, dh) plus the
+    attention consumes the layer-stacked block-pool leaves
+    (L, R, T, KV, dh), carried through the scan and written in place at
+    its own layer, plus the
     per-slot block tables via ``attn.paged_decode_attention`` — the
     dense (B, max_seq, ...) view is never materialized; the current
     token's K/V is appended into the active block in place and the
@@ -257,16 +275,17 @@ def paged_decode_step(cfg: ArchConfig, params, pool, tables, tokens,
     (logits, pool, scales).
     """
 
-    def attn_body(layer_params, hn, *kvs):
+    def attn_body(layer_params, hn, layer, *kvs):
         return attn.paged_decode_attention(
-            layer_params["attn"], hn, kvs, tables, positions,
+            layer_params["attn"], hn, kvs, layer, tables, positions,
             n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads, head_dim=cfg.head_dim,
             qk_norm=cfg.qk_norm, rope_theta=cfg.rope_theta,
             kv_dtype=kv_dtype,
         )
 
     logits, new_leaves = _decode_layers(
-        cfg, params, _paged_leaves(pool, scales), tokens, attn_body)
+        cfg, params, _paged_leaves(pool, scales), tokens, attn_body,
+        in_place=True)
     return _paged_result(logits, new_leaves, scales)
 
 
@@ -313,9 +332,9 @@ def paged_prefill_step(cfg: ArchConfig, params, pool, tables, tokens,
                          nb * T - 1).astype(jnp.int32)
     lengths = (start + C).astype(jnp.int32)      # unclipped: exact row masks
 
-    def attn_body(layer_params, hn, *kvs):
+    def attn_body(layer_params, hn, layer, *kvs):
         return attn.paged_chunk_prefill_attention(
-            layer_params["attn"], hn, kvs, tables,
+            layer_params["attn"], hn, kvs, layer, tables,
             positions, lengths,
             n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads, head_dim=cfg.head_dim,
             qk_norm=cfg.qk_norm, rope_theta=cfg.rope_theta,
@@ -324,7 +343,7 @@ def paged_prefill_step(cfg: ArchConfig, params, pool, tables, tokens,
 
     logits, new_leaves = _decode_layers(
         cfg, params, _paged_leaves(pool, scales), tokens, attn_body,
-        last=last)
+        last=last, in_place=True)
     return _paged_result(logits, new_leaves, scales)
 
 
@@ -374,9 +393,9 @@ def paged_verify_step(cfg: ArchConfig, params, pool, tables, tokens, start,
                          nb * T - 1).astype(jnp.int32)
     lengths = (start + C).astype(jnp.int32)      # unclipped: exact row masks
 
-    def attn_body(layer_params, hn, *kvs):
+    def attn_body(layer_params, hn, layer, *kvs):
         return attn.paged_chunk_prefill_attention(
-            layer_params["attn"], hn, kvs, tables,
+            layer_params["attn"], hn, kvs, layer, tables,
             positions, lengths,
             n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads, head_dim=cfg.head_dim,
             qk_norm=cfg.qk_norm, rope_theta=cfg.rope_theta,
@@ -385,7 +404,7 @@ def paged_verify_step(cfg: ArchConfig, params, pool, tables, tokens, start,
 
     logits, new_leaves = _decode_layers(
         cfg, params, _paged_leaves(pool, scales), tokens, attn_body,
-        all_rows=True)
+        all_rows=True, in_place=True)
     return _paged_result(logits, new_leaves, scales)
 
 

@@ -394,6 +394,148 @@ def test_paged_prefill_bf16():
                                rtol=0.06, atol=0.03)
 
 
+# ---------------------------------------------------------------------------
+# the paged model steps: the layer scan carries the stacked pool
+# ---------------------------------------------------------------------------
+
+STACKED_L, STACKED_T, STACKED_NB, STACKED_B = 3, 4, 4, 2
+
+
+def _stacked_pool_case(kv_dtype, seed=21):
+    """A 3-layer smoke transformer and a stacked pool whose layers hold
+    DISTINCT random contents, so reading or writing the wrong layer
+    changes the answer.  Returns (cfg, params, leaves, tables)."""
+    import dataclasses
+    from repro.configs import get_smoke
+    from repro.models import transformer
+    from repro.serving import kvquant
+
+    cfg = dataclasses.replace(get_smoke("qwen3-8b"), n_layers=STACKED_L)
+    params = transformer.init(cfg, jax.random.PRNGKey(seed))
+    L, T, nb, B = STACKED_L, STACKED_T, STACKED_NB, STACKED_B
+    R = 1 + B * nb + 2
+    r = np.random.default_rng(seed)
+    shape = (L, R, T, cfg.n_kv_heads, cfg.head_dim)
+    wide = [jnp.asarray(r.normal(size=shape), jnp.bfloat16)
+            for _ in range(2)]
+    if kv_dtype == "bf16":
+        leaves = tuple(wide)
+    else:
+        scales = [kvquant.block_scale(w, (2, 4), kv_dtype) for w in wide]
+        leaves = tuple(kvquant.quantize(w, s, kv_dtype)
+                       for w, s in zip(wide, scales)) + tuple(scales)
+    free = r.permutation(np.arange(1, R))
+    tables = jnp.asarray(free[:B * nb].reshape(B, nb), jnp.int32)
+    return cfg, params, leaves, tables
+
+
+def _layer_by_layer(cfg, params, leaves, tokens, attend, logits_of):
+    """The reference: the transformer's layer body scanned over each
+    layer's own pool ``pool[l]`` as ``xs`` (attended as a one-layer
+    stack, layer index 0, so the kernel reads that layer and nothing
+    else), the new layers restacked as ``ys``.  A scan, like the step
+    under test, so XLA fuses the layer body the same way."""
+    from repro.models.layers import mlp_apply, rms_norm
+
+    dt = jnp.dtype(cfg.compute_dtype)
+
+    def body(h, xs):
+        lp, layer_leaves = xs
+        a, kvs = attend(lp["attn"], rms_norm(h, lp["attn_norm"]),
+                        tuple(x[None] for x in layer_leaves))
+        h = h + a
+        h = h + mlp_apply(lp["mlp"], rms_norm(h, lp["mlp_norm"]),
+                          cfg.mlp_kind)
+        return h, tuple(x[0] for x in kvs)
+
+    h = params["embedding"].astype(dt)[tokens]
+    h, stacked = jax.lax.scan(body, h, (params["layers"], tuple(leaves)))
+    h = rms_norm(h, params["final_norm"])
+    return logits_of(h, params["lm_head"].astype(dt)), stacked
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("mode", ["decode", "prefill", "verify"])
+def test_paged_steps_carry_stacked_pool_like_layer_by_layer(mode, kv_dtype):
+    """``paged_{decode,prefill,verify}_step`` carry the stacked pool
+    through the layer scan and hand the kernel the layer index: logits
+    and every layer of the returned pool must be BIT-identical to
+    running the attention layer by layer on ``pool[l]``."""
+    from repro.models import attention as attn
+    from repro.models import transformer
+
+    cfg, params, leaves, tables = _stacked_pool_case(kv_dtype)
+    T, nb, B = STACKED_T, STACKED_NB, STACKED_B
+    r = np.random.default_rng(5)
+    heads = dict(n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
+                 head_dim=cfg.head_dim, qk_norm=cfg.qk_norm,
+                 rope_theta=cfg.rope_theta, kv_dtype=kv_dtype)
+    C = {"decode": 1, "prefill": 5, "verify": 3}[mode]
+    tokens = jnp.asarray(r.integers(0, cfg.vocab, (B, C)), jnp.int32)
+    start = jnp.asarray(r.integers(0, nb * T - C + 1, B), jnp.int32)
+    pool = {"k": leaves[0], "v": leaves[1]}
+    scales = None if kv_dtype == "bf16" else {"k": leaves[2],
+                                             "v": leaves[3]}
+    positions = jnp.clip(start[:, None] + jnp.arange(C)[None], 0,
+                         nb * T - 1).astype(jnp.int32)
+    lengths = start + C
+
+    if mode == "decode":
+        def step(params, pool, scales):
+            return transformer.paged_decode_step(
+                cfg, params, pool, tables, tokens, start, scales=scales,
+                kv_dtype=kv_dtype)
+
+        def attend(p, hn, kvs):
+            return attn.paged_decode_attention(p, hn, kvs, 0, tables,
+                                               start, **heads)
+
+        def logits_of(h, w):
+            return (h[:, 0] @ w).astype(jnp.float32)
+    else:
+        last = jnp.asarray(r.integers(0, C, B), jnp.int32)
+
+        def step(params, pool, scales):
+            if mode == "prefill":
+                return transformer.paged_prefill_step(
+                    cfg, params, pool, tables, tokens, start, last,
+                    scales=scales, kv_dtype=kv_dtype)
+            return transformer.paged_verify_step(
+                cfg, params, pool, tables, tokens, start, scales=scales,
+                kv_dtype=kv_dtype)
+
+        def attend(p, hn, kvs):
+            return attn.paged_chunk_prefill_attention(
+                p, hn, kvs, 0, tables, positions, lengths, start=start,
+                **heads)
+
+        def logits_of(h, w):
+            if mode == "prefill":
+                hl = jnp.take_along_axis(h, last[:, None, None], axis=1)
+                return (hl[:, 0] @ w).astype(jnp.float32)
+            return jnp.stack([(h[:, j] @ w).astype(jnp.float32)
+                              for j in range(C)], axis=1)
+
+    got = jax.jit(step)(params, pool, scales)
+    want_logits, want_leaves = jax.jit(
+        lambda params, leaves: _layer_by_layer(
+            cfg, params, leaves, tokens, attend, logits_of))(params, leaves)
+    got_leaves = (got[1]["k"], got[1]["v"])
+    if scales is not None:
+        got_leaves += (got[2]["k"], got[2]["v"])
+    assert np.array_equal(np.asarray(got[0]), np.asarray(want_logits))
+    for i, (g, w) in enumerate(zip(got_leaves, want_leaves)):
+        for l in range(STACKED_L):
+            assert np.array_equal(np.asarray(g[l], np.float32),
+                                  np.asarray(w[l], np.float32)), (i, l)
+    # the step wrote its tokens: each layer's pool moved, and differently
+    for g, before in zip(got_leaves[:2], leaves[:2]):
+        moved = [not np.array_equal(np.asarray(g[l], np.float32),
+                                    np.asarray(before[l], np.float32))
+                 for l in range(STACKED_L)]
+        assert all(moved), moved
+
+
 def test_flash_attention_rectangular_prefill_offset():
     """S_kv > S (chunked prefill against a dense cache): the causal mask
     shifts by ``S_kv - S`` — query row qi attends kv positions
